@@ -723,6 +723,8 @@ def main(argv: list[str] | None = None) -> None:
                          f"exit 1 on a >{REGRESSION_THRESHOLD:.0%} regression")
     args = ap.parse_args(argv)
     QUICK = args.quick
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     old_rows: list[dict] | None = None
     old_quick = False
     if args.compare:

@@ -5,13 +5,17 @@ with updated state from monitoring") is evaluating *many* piecewise functions
 (progress, resource usage, buffered data of every process; every candidate
 allocation of a what-if sweep à la Fig. 7) at *many* time points.
 
-TPU adaptation (see DESIGN.md): a data-dependent binary search per query is
+TPU adaptation: a data-dependent binary search per query is
 VPU-hostile, so each (function-tile × query-tile) block holds the whole
 breakpoint/coefficient table in VMEM and selects pieces with a vectorized
 compare-reduce (``idx = Σ (start ≤ t) − 1``) followed by a one-hot masked
 Horner evaluation — O(P·K) lane-parallel FLOPs per query, no gathers, no
 scalar loops.  The MXU is not involved; this is a pure VPU kernel, and block
 shapes keep the last dimension at 128 lanes.
+
+The kernels are float32 and are built with ``jax_enable_x64`` off (scoped to
+the ``pallas_call``): the sweep engine turns x64 on for the process, and the
+TPU compiler rejects the 64-bit constants and grid indices it would produce.
 """
 
 from __future__ import annotations
@@ -25,37 +29,44 @@ from jax.experimental import pallas as pl
 from .ref import _BIG, PAD_START
 
 
-def _ppoly_kernel(starts_ref, coeffs_ref, q_ref, out_ref, *, n_pieces: int, n_coef: int):
-    out_ref[...] = _eval_one(starts_ref[...], coeffs_ref[...], q_ref[...],
-                             n_pieces, n_coef)
+def _ppoly_kernel(starts_ref, q_ref, *refs, n_pieces: int):
+    *coef_refs, out_ref = refs
+    out_ref[...] = _eval_one(starts_ref[...], [c[...] for c in coef_refs],
+                             q_ref[...], n_pieces)
 
 
-def _eval_one(starts, coeffs, q, n_pieces: int, n_coef: int):
-    """Shared kernel body: evaluate (bB, P)/(bB, P, K) at (bB, bT) queries."""
+def _eval_one(starts, planes, q, n_pieces: int):
+    """Shared kernel body: evaluate (bB, P) pieces at (bB, bT) queries.
+
+    ``planes`` holds the K coefficient planes as separate (bB, P) arrays:
+    Mosaic lowers a lane slice of a (bB, P, K) block to a gather, which the
+    TPU compiler refuses, so the planes are split before the kernel.
+    """
     cmp = (starts[:, None, :] <= q[:, :, None]).astype(jnp.float32)   # (bB,bT,P)
     idx = jnp.maximum(jnp.sum(cmp, axis=-1) - 1.0, 0.0)               # (bB,bT)
-    piece_ids = jax.lax.broadcasted_iota(jnp.float32, (1, 1, n_pieces), 2)
+    piece_ids = jax.lax.broadcasted_iota(          # Mosaic iota is int-only
+        jnp.int32, (1, 1, n_pieces), 2).astype(jnp.float32)
     onehot = (idx[:, :, None] == piece_ids).astype(jnp.float32)       # (bB,bT,P)
     u = (q[:, :, None] - starts[:, None, :]) * onehot
     acc = jnp.zeros_like(u)
-    for k in range(n_coef - 1, -1, -1):
-        acc = acc * u + coeffs[:, None, :, k]
+    for c in reversed(planes):
+        acc = acc * u + c[:, None, :]
     return jnp.sum(acc * onehot, axis=-1)
 
 
 _PAD_HALF = PAD_START * 0.5  # padding-slot detection threshold
 
 
-def _ppoly_min_kernel(starts_ref, coeffs_ref, q_ref, val_ref, arg_ref,
-                      *, n_fns: int, n_pieces: int, n_coef: int):
+def _ppoly_min_kernel(starts_ref, q_ref, *refs, n_fns: int, n_pieces: int):
     """min over F stacked functions with argmin; F is a static Python loop."""
+    *coef_refs, val_ref, arg_ref = refs
     q = q_ref[...]                                      # (bB, bT)
     best = jnp.full_like(q, _BIG)
     arg = jnp.zeros_like(q)
     for f in range(n_fns):
         starts_f = starts_ref[:, f, :]                  # (bB, P)
-        coeffs_f = coeffs_ref[:, f, :, :]               # (bB, P, K)
-        v = _eval_one(starts_f, coeffs_f, q, n_pieces, n_coef)
+        planes_f = [c[:, f, :] for c in coef_refs]      # K x (bB, P)
+        v = _eval_one(starts_f, planes_f, q, n_pieces)
         valid = (starts_f[:, 0] < _PAD_HALF)[:, None]   # padding function slot?
         v = jnp.where(valid, v, _BIG)
         take = v < best                                 # strict: ties keep lowest f
@@ -78,25 +89,18 @@ def ppoly_min_eval_pallas(starts: jnp.ndarray, coeffs: jnp.ndarray, q: jnp.ndarr
     T = q.shape[-1]
     assert B % block_b == 0 and T % block_t == 0, "pad inputs to block multiples"
     grid = (B // block_b, T // block_t)
-    kernel = functools.partial(_ppoly_min_kernel, n_fns=F, n_pieces=P, n_coef=K)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, F, P), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((block_b, F, P, K), lambda i, j: (i, 0, 0, 0)),
-            pl.BlockSpec((block_b, block_t), lambda i, j: (i, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_b, block_t), lambda i, j: (i, j)),
-            pl.BlockSpec((block_b, block_t), lambda i, j: (i, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, T), jnp.float32),
-            jax.ShapeDtypeStruct((B, T), jnp.float32),
-        ],
-        interpret=interpret,
-    )(starts, coeffs, q)
+    kernel = functools.partial(_ppoly_min_kernel, n_fns=F, n_pieces=P)
+    fn_spec = pl.BlockSpec((block_b, F, P), lambda i, j: (i, 0, 0))
+    q_spec = pl.BlockSpec((block_b, block_t), lambda i, j: (i, j))
+    with jax.enable_x64(False):
+        return pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=[fn_spec, q_spec] + [fn_spec] * K,
+            out_specs=[q_spec, q_spec],
+            out_shape=[jax.ShapeDtypeStruct((B, T), jnp.float32)] * 2,
+            interpret=interpret,
+        )(starts, q, *(coeffs[..., k] for k in range(K)))
 
 
 def _first_crossing_kernel(starts_ref, c0_ref, c1_ref, c2_ref, plen_ref,
@@ -143,21 +147,17 @@ def ppoly_first_crossing_pallas(starts: jnp.ndarray, coeffs: jnp.ndarray,
                             jnp.full((B, 1), PAD_START, starts.dtype)],
                            axis=1) - starts
     grid = (B // block_b, T // block_t)
-    return pl.pallas_call(
-        _first_crossing_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, P), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_b, P), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_b, P), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_b, P), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_b, P), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_b, block_t), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((block_b, block_t), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((B, T), jnp.float32),
-        interpret=interpret,
-    )(starts, c0, c1, c2, plen, y)
+    fn_spec = pl.BlockSpec((block_b, P), lambda i, j: (i, 0))
+    y_spec = pl.BlockSpec((block_b, block_t), lambda i, j: (i, j))
+    with jax.enable_x64(False):
+        return pl.pallas_call(
+            _first_crossing_kernel,
+            grid=grid,
+            in_specs=[fn_spec] * 5 + [y_spec],
+            out_specs=y_spec,
+            out_shape=jax.ShapeDtypeStruct((B, T), jnp.float32),
+            interpret=interpret,
+        )(starts, c0, c1, c2, plen, y)
 
 
 def ppoly_eval_pallas(starts: jnp.ndarray, coeffs: jnp.ndarray, q: jnp.ndarray,
@@ -173,16 +173,15 @@ def ppoly_eval_pallas(starts: jnp.ndarray, coeffs: jnp.ndarray, q: jnp.ndarray,
     assert B % block_b == 0 and T % block_t == 0, "pad inputs to block multiples"
 
     grid = (B // block_b, T // block_t)
-    kernel = functools.partial(_ppoly_kernel, n_pieces=P, n_coef=K)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, P), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_b, P, K), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((block_b, block_t), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((block_b, block_t), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((B, T), jnp.float32),
-        interpret=interpret,
-    )(starts, coeffs, q)
+    kernel = functools.partial(_ppoly_kernel, n_pieces=P)
+    fn_spec = pl.BlockSpec((block_b, P), lambda i, j: (i, 0))
+    q_spec = pl.BlockSpec((block_b, block_t), lambda i, j: (i, j))
+    with jax.enable_x64(False):
+        return pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=[fn_spec, q_spec] + [fn_spec] * K,
+            out_specs=q_spec,
+            out_shape=jax.ShapeDtypeStruct((B, T), jnp.float32),
+            interpret=interpret,
+        )(starts, q, *(coeffs[..., k] for k in range(K)))

@@ -160,8 +160,10 @@ def _mc_phase(live, draws: int) -> None:
 def main(argv: list[str] | None = None) -> None:
     from repro.analysis import AnalysisService
     from repro.configs.paper_workflow import build_workflow
+    from repro.launch.compile_cache import use_compile_cache
 
     args = build_parser().parse_args(argv)
+    use_compile_cache()
     svc = AnalysisService(backend=args.backend, linger_s=args.linger_ms / 1e3,
                           store=args.store)
     try:

@@ -1515,6 +1515,7 @@ class JaxSweepEngine:
         Each entry: ``{"B", "iter_cap", "ramps", "sig", "blob"}``.
         """
         from jax import export as jax_export
+        from jax._src.config import traceback_in_locations_limit
 
         entries = list(self._aot_blobs)
         have = {(e["B"], 1, e["iter_cap"], e["ramps"], _canon_sig(e["sig"]))
@@ -1528,8 +1529,12 @@ class JaxSweepEngine:
                 if (B, 1, cap, ramps, sig) in have:
                     continue
                 have.add((B, 1, cap, ramps, sig))
-                exported = jax_export.export(
-                    jax.jit(self._make_run(B, cap, ramps)))(shapes)
+                # no traceback locations: they name the caller's source
+                # lines, so one plan exported from two call sites would
+                # serialize to different bytes (thread-local, this call only)
+                with traceback_in_locations_limit(0):
+                    exported = jax_export.export(
+                        jax.jit(self._make_run(B, cap, ramps)))(shapes)
                 entries.append({"B": int(B), "iter_cap": int(cap),
                                 "ramps": bool(ramps), "sig": sig,
                                 "blob": exported.serialize()})
