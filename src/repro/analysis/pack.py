@@ -32,6 +32,7 @@ from dataclasses import dataclass, field
 from typing import Any, Mapping, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.ppoly import PPoly
 from repro.sweep.batch import Scenario, ScenarioBatch
@@ -163,36 +164,41 @@ class ScenarioPack:
     def build(plan: Any, scenario_list: Sequence[Any], *,
               classify: bool = True) -> "ScenarioPack":
         """Resolve, classify, and pack ``scenario_list`` against ``plan``."""
-        batch = ScenarioBatch(plan.workflow, list(scenario_list))
-        scenarios = [_copy_scenario(sc) for sc in batch.scenarios]
-        labels = batch.labels()
-        B = len(scenarios)
-        if classify:
-            reasons = [plan._classify(sc) for sc in scenarios]
-            bat_idx = [i for i, r in enumerate(reasons) if r is None]
-            loop_idx = [i for i, r in enumerate(reasons) if r is not None]
-            reason = next((r for r in reasons if r is not None), None)
-            loop_reasons = {i: r for i, r in enumerate(reasons)
-                            if r is not None}
-        else:
-            bat_idx, loop_idx, reason = [], list(range(B)), None
-            loop_reasons = {}
-        proc_args: dict[str, dict[str, dict[str, BPL]]] = {}
-        if bat_idx:
-            try:
-                proc_args = _pack_proc_args(plan, [scenarios[i] for i in bat_idx])
-            except UnsupportedScenario as e:
-                # defensive: packing found an out-of-class construct the
-                # static audit missed — route everything to the scalar loop
-                for i in bat_idx:
-                    loop_reasons.setdefault(i, str(e))
-                loop_idx = sorted(loop_idx + bat_idx)
-                bat_idx, proc_args = [], {}
-                reason = reason or str(e)
-        return ScenarioPack(plan=plan, labels=labels, scenarios=scenarios,
-                            bat_idx=bat_idx, loop_idx=loop_idx, reason=reason,
-                            proc_args=proc_args, loop_reasons=loop_reasons,
-                            ramps=_compute_ramps(proc_args))
+        with TraceAnnotation("bm.pack"):
+            batch = ScenarioBatch(plan.workflow, list(scenario_list))
+            scenarios = [_copy_scenario(sc) for sc in batch.scenarios]
+            labels = batch.labels()
+            B = len(scenarios)
+            if classify:
+                reasons = [plan._classify(sc) for sc in scenarios]
+                bat_idx = [i for i, r in enumerate(reasons) if r is None]
+                loop_idx = [i for i, r in enumerate(reasons) if r is not None]
+                reason = next((r for r in reasons if r is not None), None)
+                loop_reasons = {i: r for i, r in enumerate(reasons)
+                                if r is not None}
+            else:
+                bat_idx, loop_idx, reason = [], list(range(B)), None
+                loop_reasons = {}
+            proc_args: dict[str, dict[str, dict[str, BPL]]] = {}
+            if bat_idx:
+                try:
+                    proc_args = _pack_proc_args(
+                        plan, [scenarios[i] for i in bat_idx])
+                except UnsupportedScenario as e:
+                    # defensive: packing found an out-of-class construct the
+                    # static audit missed — route everything to the scalar
+                    # loop
+                    for i in bat_idx:
+                        loop_reasons.setdefault(i, str(e))
+                    loop_idx = sorted(loop_idx + bat_idx)
+                    bat_idx, proc_args = [], {}
+                    reason = reason or str(e)
+            return ScenarioPack(plan=plan, labels=labels,
+                                scenarios=scenarios, bat_idx=bat_idx,
+                                loop_idx=loop_idx, reason=reason,
+                                proc_args=proc_args,
+                                loop_reasons=loop_reasons,
+                                ramps=_compute_ramps(proc_args))
 
     # ------------------------------------------------------------------
     def shard(self, n: int | None = None) -> "ScenarioPack":

@@ -38,6 +38,7 @@ import warnings
 from typing import Any, Mapping, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.ppoly import PPoly
 from repro.core.solver import ProgressResult
@@ -556,8 +557,9 @@ class CompiledWorkflow:
                 f"function class fell back to the scalar loop backend "
                 f"({reason}); see Report.backends for the per-scenario "
                 "routing", UserWarning, stacklevel=2)
-        rep = self._merge(pack, bat_idx, batched, loop_runs, engine_used,
-                          loop_reasons)
+        with TraceAnnotation("bm.report"):
+            rep = self._merge(pack, bat_idx, batched, loop_runs, engine_used,
+                              loop_reasons)
         rep.engine_fallback = engine_fallback
         return rep
 

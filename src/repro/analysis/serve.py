@@ -71,6 +71,7 @@ deterministically exercised by :mod:`repro.analysis.faults`):
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 import warnings
@@ -81,6 +82,7 @@ from pathlib import Path
 from typing import Any, Iterable, Mapping, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.ppoly import PPoly
 from repro.core.workflow import Workflow
@@ -205,6 +207,9 @@ class ServiceStats:
     quarantined: int = 0       #: malformed monitoring deltas dropped by ingest
     #: quarantine-reason census (reason -> delta count), service-cumulative
     quarantine_reasons: dict = field(default_factory=dict)
+    #: seconds from submit to the start of each request's own sweep, summed
+    queue_wait_s: float = 0.0
+    queue_waits: int = 0       #: requests ``queue_wait_s`` sums over
     latencies_s: deque = field(default_factory=lambda: deque(maxlen=4096))
 
     def latency_quantiles(self, qs: Sequence[float] = (0.5, 0.99)
@@ -262,6 +267,8 @@ class ServiceStats:
             "top_quarantine_reasons": sorted(
                 self.quarantine_reasons.items(), key=lambda kv: -kv[1])[:3],
             "latency_p50_s": p50, "latency_p99_s": p99,
+            "queue_wait_s": self.queue_wait_s,
+            "queue_waits": self.queue_waits,
         }
 
 
@@ -270,11 +277,13 @@ class _Request:
     plan: CompiledWorkflow
     future: Future
     t_submit: float
+    id: int                            # shared by the chunks of one submit_mc
     scenarios: list | None = None      # coalescable what-if query
     pack: ScenarioPack | None = None   # pre-packed (online re-analysis)
     optimize: dict | None = None       # plan.optimize kwargs (solo request)
     deadline: float | None = None      # absolute perf_counter() deadline
     retries: int = 0                   # backoff retries already spent
+    t_sweep: float | None = None       # when its own sweep first began
 
     def expired(self, now: float) -> bool:
         return self.deadline is not None and now > self.deadline
@@ -349,6 +358,7 @@ class AnalysisService:
         self._plan_keys: dict[int, tuple] = {}  # id(plan) -> fingerprint
         self._warmed = False
         self._queue: list[_Request] = []
+        self._ids = itertools.count(1)        # request ids (trace metadata)
         self._inflight: list[_Request] = []   # worker-thread only
         self._plans: dict[tuple, CompiledWorkflow] = {}
         self._engines: dict[tuple, Any] = {}
@@ -614,9 +624,11 @@ class AnalysisService:
                       scenarios: list | None = None,
                       pack: ScenarioPack | None = None,
                       optimize: dict | None = None,
-                      deadline_s: float | None = None) -> _Request:
+                      deadline_s: float | None = None,
+                      req_id: int | None = None) -> _Request:
         now = time.perf_counter()
         return _Request(plan=plan, future=Future(), t_submit=now,
+                        id=next(self._ids) if req_id is None else req_id,
                         scenarios=scenarios, pack=pack, optimize=optimize,
                         deadline=(None if deadline_s is None
                                   else now + float(deadline_s)))
@@ -727,10 +739,12 @@ class AnalysisService:
         chunk_w = self.max_batch if max_batch is None else int(max_batch)
         if chunk_w < 1:
             raise ValueError(f"max_batch must be >= 1, got {chunk_w}")
-        samples = sample_spec(plan, spec, n, seed=seed)
+        rid = next(self._ids)
+        with TraceAnnotation("bm.mc.sample", req=rid):
+            samples = sample_spec(plan, spec, n, seed=seed)
         reqs = [self._make_request(
                     plan, scenarios=samples.scenarios[lo:lo + chunk_w],
-                    deadline_s=deadline_s)
+                    deadline_s=deadline_s, req_id=rid)
                 for lo in range(0, n, chunk_w)]
         chunk_futs = self._enqueue_many(reqs)
         out: "Future[MCReport]" = Future()
@@ -756,9 +770,10 @@ class AnalysisService:
                 if state["pending"]:
                     return
             try:
-                rep = concat_reports(ft.result() for ft in chunk_futs)
-                out.set_result(mc_report_from_sweep(
-                    rep, samples, quantile_levels))
+                with TraceAnnotation("bm.mc.report", req=rid):
+                    rep = concat_reports(ft.result() for ft in chunk_futs)
+                    mc = mc_report_from_sweep(rep, samples, quantile_levels)
+                out.set_result(mc)
             except Exception as e:  # noqa: BLE001 — surface via the future
                 out.set_exception(e)
 
@@ -1061,9 +1076,23 @@ class AnalysisService:
             return
         self._finish(req, rep)
 
+    def _count_queue_wait(self, reqs: list[_Request]) -> None:
+        """Feed ``queue_wait_s`` / ``queue_waits`` as a sweep begins; a
+        request retried in a later sweep counts its first wait only."""
+        now = time.perf_counter()
+        fresh = [r for r in reqs if r.t_sweep is None]
+        for r in fresh:
+            r.t_sweep = now
+        with self._lock:
+            self.stats.queue_wait_s += sum(now - r.t_submit for r in fresh)
+            self.stats.queue_waits += len(fresh)
+
     def _sweep_pack(self, plan: CompiledWorkflow, req: _Request) -> None:
+        self._count_queue_wait([req])
         try:
-            rep = self._do_sweep(plan, req.pack, req.pack.B)
+            with TraceAnnotation("bm.sweep", req=req.id, n_req=1,
+                                 rows=req.pack.B):
+                rep = self._do_sweep(plan, req.pack, req.pack.B)
         except Exception as e:  # noqa: BLE001 — fail THIS request only
             self._retry_or_fail(plan, req, e,
                                 lambda: self._sweep_pack(plan, req))
@@ -1072,6 +1101,7 @@ class AnalysisService:
 
     def _sweep_chunk(self, plan: CompiledWorkflow,
                      chunk: list[_Request]) -> None:
+        self._count_queue_wait(chunk)
         scs = [sc for req in chunk for sc in req.scenarios]
         B = len(scs)
         pad = 0
@@ -1081,7 +1111,10 @@ class AnalysisService:
             # replicate the last scenario and are never handed to a client
             pad = min(_pow2_bucket(B), self.max_batch) - B
         try:
-            rep = self._do_sweep(plan, plan.prepare(scs + [scs[-1]] * pad), B)
+            with TraceAnnotation("bm.sweep", req=chunk[0].id,
+                                 n_req=len(chunk), rows=B):
+                pack = plan.prepare(scs + [scs[-1]] * pad)
+                rep = self._do_sweep(plan, pack, B)
         except Exception as e:  # noqa: BLE001
             if len(chunk) == 1:
                 req = chunk[0]

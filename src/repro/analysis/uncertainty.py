@@ -32,6 +32,7 @@ from dataclasses import dataclass, field
 from typing import Any, Mapping, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.ppoly import PPoly
 from repro.sweep.batch import Scenario
@@ -247,43 +248,47 @@ def sample_spec(plan: Any, spec: Any, n: int, *args,
             col[lo:lo + ng] = vals
 
         # materialize one concrete Scenario per draw
-        factor_axes = [(ax, vals) for ax, vals in axes_g if ax.slot is None]
-        ramp_axes: dict[tuple[str, str], list[tuple[int, np.ndarray]]] = {}
-        for ax, vals in axes_g:
-            if ax.slot is not None:
-                ramp_axes.setdefault((ax.proc, ax.name), []).append(
-                    (ax.slot, vals))
-        base_of = {(ax.proc, ax.name): _base_fn(plan, ax.proc, ax.name,
-                                                ax.kind == "resource")
-                   for ax, _ in factor_axes}
-        for i in range(ng):
-            res_in: dict[tuple[str, str], PPoly] = {}
-            dat_in: dict[tuple[str, str], PPoly] = {}
-            for (proc, name, is_res), fn in fixed_fns.items():
-                (res_in if is_res else dat_in)[(proc, name)] = fn
-            for ax, vals in factor_axes:
-                base = base_of[(ax.proc, ax.name)]
-                f = float(vals[i])
-                if ax.kind == "resource":
-                    res_in[(ax.proc, ax.name)] = base * f
-                else:
-                    if f <= 0.0:
-                        raise ValueError(
-                            f"mc: draw {i} sampled non-positive data "
-                            f"speed-up {f:g} for {ax.label}; data-input "
-                            "factor distributions must have positive support")
-                    dat_in[(ax.proc, ax.name)] = speed_up_data(base, f)
-            for (proc, name), slots in ramp_axes.items():
-                tpl = ramp_templates[(proc, name)]
-                rates = [r if not isinstance(r, Dist) else 0.0
-                         for r in tpl.rates]
-                for slot, vals in slots:
-                    rates[slot] = float(vals[i])
-                res_in[(proc, name)] = PPoly.pwlinear(list(tpl.times), rates)
-            scenarios_out.append(Scenario(
-                label=f"{group_labels[g]}#{i}",
-                resource_inputs=res_in, data_inputs=dat_in))
-            labels.append(f"{group_labels[g]}#{i}")
+        with TraceAnnotation("bm.mc.materialize"):
+            factor_axes = [(ax, vals) for ax, vals in axes_g
+                           if ax.slot is None]
+            ramp_axes: dict[tuple[str, str], list[tuple[int, np.ndarray]]] = {}
+            for ax, vals in axes_g:
+                if ax.slot is not None:
+                    ramp_axes.setdefault((ax.proc, ax.name), []).append(
+                        (ax.slot, vals))
+            base_of = {(ax.proc, ax.name): _base_fn(plan, ax.proc, ax.name,
+                                                    ax.kind == "resource")
+                       for ax, _ in factor_axes}
+            for i in range(ng):
+                res_in: dict[tuple[str, str], PPoly] = {}
+                dat_in: dict[tuple[str, str], PPoly] = {}
+                for (proc, name, is_res), fn in fixed_fns.items():
+                    (res_in if is_res else dat_in)[(proc, name)] = fn
+                for ax, vals in factor_axes:
+                    base = base_of[(ax.proc, ax.name)]
+                    f = float(vals[i])
+                    if ax.kind == "resource":
+                        res_in[(ax.proc, ax.name)] = base * f
+                    else:
+                        if f <= 0.0:
+                            raise ValueError(
+                                f"mc: draw {i} sampled non-positive data "
+                                f"speed-up {f:g} for {ax.label}; data-input "
+                                "factor distributions must have positive "
+                                "support")
+                        dat_in[(ax.proc, ax.name)] = speed_up_data(base, f)
+                for (proc, name), slots in ramp_axes.items():
+                    tpl = ramp_templates[(proc, name)]
+                    rates = [r if not isinstance(r, Dist) else 0.0
+                             for r in tpl.rates]
+                    for slot, vals in slots:
+                        rates[slot] = float(vals[i])
+                    res_in[(proc, name)] = PPoly.pwlinear(list(tpl.times),
+                                                          rates)
+                scenarios_out.append(Scenario(
+                    label=f"{group_labels[g]}#{i}",
+                    resource_inputs=res_in, data_inputs=dat_in))
+                labels.append(f"{group_labels[g]}#{i}")
 
     return MCSamples(scenarios=scenarios_out, axes=all_axes, values=values,
                      seed=int(seed), n=n, group_of=group_of,

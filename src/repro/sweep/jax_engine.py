@@ -61,6 +61,7 @@ jax.config.update("jax_enable_x64", True)
 
 import jax.numpy as jnp  # noqa: E402  (after the x64 switch)
 from jax import lax  # noqa: E402
+from jax.profiler import TraceAnnotation  # noqa: E402
 
 from repro.core.ppoly import PPoly, TIME_TOL, VAL_RTOL  # noqa: E402
 from repro.kernels.ppoly_eval.ref import PAD_START  # noqa: E402
@@ -1403,24 +1404,30 @@ class JaxSweepEngine:
         if cache is not None and key in cache:
             dev = cache[key]
         else:
-            if callable(args):
-                args = args()
-            largs = self.level_args(args, B, ramps)
-            if Bp != B:
-                largs = self._pad_level_args(largs, B, Bp)
-            dev = self.device_args(largs, Bp, shards)
+            with TraceAnnotation("bm.engine.stage"):
+                if callable(args):
+                    args = args()
+                largs = self.level_args(args, B, ramps)
+                if Bp != B:
+                    largs = self._pad_level_args(largs, B, Bp)
+            with TraceAnnotation("bm.engine.put"):
+                dev = self.device_args(largs, Bp, shards)
             if cache is not None:
                 cache[key] = dev
         pkey = (Bp, shards, ramps)
         first = pkey not in self._proven_caps
         cap = self._proven_caps.get(pkey, self.iter_cap)
         while True:
-            fn = self._lookup_aot(Bp, shards, cap, ramps, dev)
-            if fn is None:
-                self._record_call(Bp, shards, cap, ramps, dev)
-                fn = self._get_compiled(Bp, shards, cap, ramps)
-            out = fn(dev)
-            if not bool(np.asarray(out["__overflow__"]).any()):
+            # one ladder step: dispatch, the wait for the device, and the
+            # overflow flag's readback
+            with TraceAnnotation("bm.engine.call"):
+                fn = self._lookup_aot(Bp, shards, cap, ramps, dev)
+                if fn is None:
+                    self._record_call(Bp, shards, cap, ramps, dev)
+                    fn = self._get_compiled(Bp, shards, cap, ramps)
+                out = fn(dev)
+                overflow = bool(np.asarray(out["__overflow__"]).any())
+            if not overflow:
                 break
             cap *= 2
             if cap > MAX_ITER_CAP:
@@ -1439,7 +1446,8 @@ class JaxSweepEngine:
                           for ps in self.spec.procs), default=1)
             cap = min(cap, 1 << max(actual - 1, 0).bit_length())
         self._proven_caps[pkey] = cap
-        return self._wrap(out, B, shards, scenario_ids)
+        with TraceAnnotation("bm.engine.fetch"):
+            return self._wrap(out, B, shards, scenario_ids)
 
     def _wrap(self, out, B: int, shards: int,
               scenario_ids: list[int] | None = None,

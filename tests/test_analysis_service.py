@@ -21,6 +21,7 @@ Contracts under test:
 from __future__ import annotations
 
 import threading
+import time
 import warnings
 
 import numpy as np
@@ -107,6 +108,25 @@ def test_poisoned_request_fails_alone(plan):
     np.testing.assert_array_equal(
         rep.makespans, plan.sweep(plan.prepare(good)).makespans)
     assert svc.snapshot()["solo_retries"] == 2
+
+
+@pytest.mark.parametrize("kind", ["submit", "submit_pack"])
+def test_queue_wait_counts_time_before_each_sweep(plan, kind):
+    """``queue_wait_s`` sums, over requests, submit -> start of the
+    request's own sweep; ``queue_waits`` counts the requests."""
+    svc = AnalysisService(autostart=False)
+    for x in (0.3, 0.6):
+        if kind == "submit":
+            fut = svc.submit(sweep_scenarios([x]), plan=plan)
+        else:
+            fut = svc.submit_pack(plan.prepare(sweep_scenarios([x])))
+    time.sleep(0.2)
+    svc.start()
+    fut.result(timeout=600)
+    svc.close()
+    snap = svc.snapshot()
+    assert snap["queue_wait_s"] >= 0.4
+    assert snap["queue_waits"] == 2
 
 
 # -------------------------------------------------------------- plan cache --

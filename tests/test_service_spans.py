@@ -1,0 +1,158 @@
+"""Trace spans of the served path: the ``bm.*`` ``TraceAnnotation``s that
+name the host's time per layer (PERF.md, "Spans and counters").
+
+A small Monte Carlo call (64 draws in two chunks of 32) and one coalesced
+what-if query (three requests in one sweep) are served twice untraced, to
+warm every shape, then once each under ``jax.profiler.trace``; the traces
+are read back with ``ProfileData``.  Checked: the span names are exactly
+the documented set, they nest by layer, one ``bm.sweep`` per counted
+sweep, the spans of one Monte Carlo call carry its request id on both
+threads, their number grows with the chunks and not with the draws, and
+the profiler leaves every result bit-identical.
+"""
+
+from __future__ import annotations
+
+import glob
+import re
+from pathlib import Path
+
+import jax
+import numpy as np
+import pytest
+from jax.profiler import ProfileData
+
+from repro.analysis import AnalysisService
+from repro.configs.paper_workflow import (build_workflow, mc_spec,
+                                          sweep_scenarios)
+
+ROOT = Path(__file__).resolve().parent.parent
+N_DRAWS, CHUNK = 64, 32
+WHATIF = [sweep_scenarios([x, x + 0.05]) for x in (0.2, 0.4, 0.6)]
+
+
+def documented_spans() -> set:
+    """The span names of PERF.md's "Spans and counters" table."""
+    text = (ROOT / "PERF.md").read_text()
+    return set(re.findall(r"^\| `(bm\.[a-z.]+)` \|", text, re.M))
+
+
+def serve_mc(plan):
+    with AnalysisService(plan, backend="jax", max_batch=CHUNK) as svc:
+        s0 = svc.snapshot()
+        mc = svc.query_mc(mc_spec(), N_DRAWS, seed=7, timeout=600)
+        return [mc.report], s0, svc.snapshot()
+
+
+def serve_whatif(plan):
+    svc = AnalysisService(plan, backend="jax", max_batch=CHUNK,
+                          autostart=False)
+    s0 = svc.snapshot()
+    futs = [svc.submit(scs) for scs in WHATIF]   # queued: one fused sweep
+    svc.start()
+    reps = [f.result(timeout=600) for f in futs]
+    svc.close()
+    return reps, s0, svc.snapshot()
+
+
+def spans(trace_dir: str) -> list:
+    """``(name, start, end, thread, stats)`` of every ``bm.*`` host event;
+    a thread is the index of its line on the host plane."""
+    path = max(glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for k, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name.startswith("bm."):
+                    s = int(ev.start_ns)
+                    out.append((ev.name, s, s + int(ev.duration_ns),
+                                (plane.name, k), dict(ev.stats)))
+    return out
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """``{kind: (untraced reports, traced reports, stats before, stats
+    after, spans)}`` for ``mc`` and ``whatif``."""
+    plan = build_workflow(0.5).compile()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    out = {}
+    for kind, serve in (("mc", serve_mc), ("whatif", serve_whatif)):
+        serve(plan)                    # compiles, then the proven-cap ratchet
+        plain = serve(plan)[0]
+        d = str(tmp_path_factory.mktemp(f"trace_{kind}"))
+        with jax.profiler.trace(d, profiler_options=opts):
+            reps, s0, s1 = serve(plan)
+        out[kind] = (plain, reps, s0, s1, spans(d))
+    return out
+
+
+def inside(child, parent) -> bool:
+    return (child[3] == parent[3] and parent[1] <= child[1]
+            and child[2] <= parent[2])
+
+
+def test_span_names_are_the_documented_set(served):
+    doc = documented_spans()
+    assert len(doc) == 10, doc
+    seen = {s[0] for kind in served for s in served[kind][4]}
+    assert seen == doc
+
+
+@pytest.mark.parametrize("child,parent", [
+    ("bm.pack", "bm.sweep"), ("bm.engine.stage", "bm.sweep"),
+    ("bm.engine.put", "bm.sweep"), ("bm.engine.call", "bm.sweep"),
+    ("bm.engine.fetch", "bm.sweep"), ("bm.report", "bm.sweep"),
+    ("bm.mc.materialize", "bm.mc.sample")])
+def test_spans_nest_by_layer(served, child, parent):
+    for kind in served:
+        sp = served[kind][4]
+        kids = [s for s in sp if s[0] == child]
+        if child.startswith("bm.mc.") and kind != "mc":
+            assert not kids
+            continue
+        assert kids, (kind, child)
+        parents = [s for s in sp if s[0] == parent]
+        assert all(any(inside(c, p) for p in parents) for c in kids)
+
+
+@pytest.mark.parametrize("kind", ["mc", "whatif"])
+def test_one_sweep_span_per_counted_sweep(served, kind):
+    _plain, _reps, s0, s1, sp = served[kind]
+    sweeps = [s for s in sp if s[0] == "bm.sweep"]
+    assert len(sweeps) == s1["sweeps"] - s0["sweeps"] > 0
+    if kind == "whatif":
+        assert [(s[4]["n_req"], s[4]["rows"]) for s in sweeps] == [(3, 6)]
+
+
+def test_mc_spans_carry_one_request_id_on_both_threads(served):
+    sp = served["mc"][4]
+    tagged = [s for s in sp if "req" in s[4]]
+    assert {s[0] for s in tagged} == {"bm.mc.sample", "bm.sweep",
+                                      "bm.mc.report"}
+    assert len({s[4]["req"] for s in tagged}) == 1
+    caller = next(s[3] for s in sp if s[0] == "bm.mc.sample")
+    worker = {s[3] for s in sp if s[0] == "bm.sweep"}
+    assert worker and caller not in worker
+
+
+def test_spans_per_call_grow_with_chunks_not_draws(served):
+    sp = served["mc"][4]
+    chunks = -(-N_DRAWS // CHUNK)
+    assert sum(s[0] == "bm.sweep" for s in sp) == chunks
+    assert len(sp) <= 10 * chunks
+
+
+@pytest.mark.parametrize("kind", ["mc", "whatif"])
+def test_profiler_leaves_results_bit_identical(served, kind):
+    plain, traced = served[kind][0], served[kind][1]
+    assert len(plain) == len(traced)
+    for a, b in zip(plain, traced):
+        assert set(a.backends) == {"jax"}
+        np.testing.assert_array_equal(a.makespans, b.makespans)
+        np.testing.assert_array_equal(a.share_seconds, b.share_seconds)
+        for n in a.order:
+            np.testing.assert_array_equal(a.finish[n], b.finish[n])
