@@ -5,8 +5,9 @@
 Point estimates hide risk: the paper workflow's makespan is a single number
 only if every link and CPU delivers exactly its nominal rate.  ``plan.mc``
 replaces scalar what-ifs with *distributions* — each resource cap or data
-input becomes a ``dist.*`` draw, every draw materializes as one scenario on
-the sharded batch axis, and the whole sample runs as fused sweep calls.  The
+input becomes a ``dist.*`` draw, every draw is one row of the sharded batch
+axis, packed from its factor arrays, and the whole sample runs as fused
+sweep calls.  The
 resulting ``MCReport`` answers the operator questions directly: "what is the
 p95 makespan?", "how likely do we miss the SLO?", "which factor's
 uncertainty should we buy down first?".

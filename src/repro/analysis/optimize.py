@@ -43,7 +43,7 @@ from typing import Any, Sequence
 
 import numpy as np
 
-from .pack import CapAxis, PwAxis, ThetaMap
+from .pack import CapAxis, PwAxis, ScenarioPack, ThetaMap
 from .scenarios import parse_key
 
 __all__ = ["OptimizeReport", "Space", "cap_space", "mc_quantile",
@@ -365,8 +365,8 @@ def run_optimize(plan, objective: Any = "makespan", space: Space | None = None,
             [k for s in specs for k in (*s.resources, *s.data)])
         obj_seed = objective.seed if seed is None else int(seed)
         samples = sample_spec(plan, spec, objective.n, seed=obj_seed)
-        pack = plan.prepare(samples.scenarios)
-        n, q = len(samples.scenarios), objective.q
+        pack = ScenarioPack.from_draws(plan, samples)
+        n, q = samples.n, objective.q
         desc = (f"p{100 * objective.q:g} makespan over n={n} draws "
                 f"(seed={obj_seed})")
     elif objective == "makespan":

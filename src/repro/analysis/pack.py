@@ -13,6 +13,9 @@ hands ``plan.sweep(pack)`` a solver-ready handle:
 * the padded override arrays, base-input single-row broadcasts, and
   pre-composed data ceilings in the ``kernels/ppoly_eval`` layout.
 
+Monte Carlo draws take :meth:`ScenarioPack.from_draws` instead: it builds
+the same arrays from the sampled factor columns, with no scenario per draw.
+
 Re-sweep entry points::
 
     pack = plan.prepare(scenarios)          # resolve+classify+pack: once
@@ -35,8 +38,11 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.core.ppoly import PPoly
+from repro.kernels.ppoly_eval.ref import PAD_START
 from repro.sweep.batch import Scenario, ScenarioBatch
 from repro.sweep.plin import BPL, UnsupportedScenario, is_batchable_resource
+
+from .report import take_rows
 
 __all__ = ["CapAxis", "PwAxis", "ScenarioPack", "ThetaMap"]
 
@@ -58,7 +64,7 @@ class ScenarioPack:
 
     plan: Any = field(repr=False)
     labels: list[str]
-    scenarios: list[Scenario] = field(repr=False)
+    scenarios: Sequence[Scenario] = field(repr=False)
     bat_idx: list[int]
     loop_idx: list[int]
     reason: str | None
@@ -201,6 +207,72 @@ class ScenarioPack:
                                 ramps=_compute_ramps(proc_args))
 
     # ------------------------------------------------------------------
+    @staticmethod
+    def from_draws(plan: Any, samples: Any, rows: Any = None, *,
+                   pad_to: int | None = None) -> "ScenarioPack":
+        """Pack Monte Carlo draws ``rows`` (default: all) of ``samples``, an
+        :class:`~repro.analysis.uncertainty.MCSamples`, straight from their
+        factor arrays: no :class:`Scenario` per draw.
+
+        Every sampled input is a scale of a base function, so its
+        ``(B, P)`` planes take a few array operations: a resource scaled by
+        ``f`` is the base's planes times ``f``; a data input sped up by
+        ``f`` is ``starts / f``, ``c0``, ``c1 * f``, ``c2 * f**2`` (local
+        coordinates); a sampled ramp interpolates its rates over fixed
+        times.  Shapes, piece counts and the degree signature are those
+        :meth:`build` gives for the same draws, so the engine's compile keys
+        do not change.
+
+        Routing is decided on whole columns: a spec group's rows leave the
+        batched class when one of its fixed or base inputs does, and a
+        resource row when its factor is negative.  Only those rows get their
+        :class:`Scenario` (from ``samples.scenarios``), for the scalar loop
+        and their ``loop_reasons``.  ``pad_to`` replicates the last row up
+        to that width (the service's pow2 buckets).
+        """
+        with TraceAnnotation("bm.pack"):
+            rows = (np.arange(samples.n) if rows is None
+                    else np.asarray(rows, dtype=np.int64))
+            if pad_to is not None and pad_to > len(rows):
+                rows = np.concatenate(
+                    [rows, np.full(pad_to - len(rows), rows[-1])])
+            scenarios = samples.scenarios[rows]
+            grp = samples.group_of[rows]
+            ok = np.zeros(len(rows), dtype=bool)
+            inputs: dict[int, tuple[dict, dict]] = {}
+            for g in np.unique(grp).tolist():
+                res: dict[tuple[str, str], Any] = {}
+                dat: dict[tuple[str, str], Any] = {}
+                for inp in samples.inputs[g]:
+                    (res if inp.is_res else dat)[inp.key] = inp
+                inputs[g] = (res, dat)
+                sel = grp == g
+                if _draws_in_class(plan, res, dat):
+                    ok[sel] = True
+                    for inp in res.values():
+                        if inp.col is not None:
+                            ok[sel] &= samples.values[inp.col][rows[sel]] >= 0.0
+            loop_idx = np.flatnonzero(~ok).tolist()
+            loop_reasons = {i: plan._classify(scenarios[i])
+                            or "negative resource factor" for i in loop_idx}
+            bat = np.flatnonzero(ok)
+            proc_args: dict[str, dict[str, dict[str, BPL]]] = {}
+            if len(bat):
+                bgrp, brows = grp[bat], rows[bat]
+                groups = [(inputs[g], np.flatnonzero(bgrp == g),
+                           brows[bgrp == g]) for g in np.unique(bgrp).tolist()]
+                proc_args = _pack_draw_args(plan, samples.values, groups,
+                                            len(bat))
+            return ScenarioPack(plan=plan,
+                                labels=[samples.labels[j] for j in rows.tolist()],
+                                scenarios=scenarios, bat_idx=bat.tolist(),
+                                loop_idx=loop_idx,
+                                reason=next(iter(loop_reasons.values()), None),
+                                proc_args=proc_args,
+                                loop_reasons=loop_reasons,
+                                ramps=_compute_ramps(proc_args))
+
+    # ------------------------------------------------------------------
     def shard(self, n: int | None = None) -> "ScenarioPack":
         """A copy of this pack whose batched partition runs sharded over
         ``n`` devices (default: every local JAX device).
@@ -262,7 +334,7 @@ class ScenarioPack:
                 for name, args in self.proc_args.items()}
         return ScenarioPack(plan=self.plan,
                             labels=[self.labels[i] for i in idx],
-                            scenarios=[self.scenarios[i] for i in idx],
+                            scenarios=take_rows(self.scenarios, idx),
                             bat_idx=new_bat, loop_idx=new_loop,
                             reason=next(iter(loop_reasons.values()), None),
                             proc_args=proc_args, loop_reasons=loop_reasons,
@@ -403,6 +475,115 @@ def _pack_proc_args(plan: Any, bats: list[Scenario],
                 args["res"][r] = BPL.from_ppolys(fns)
             else:
                 args["res"][r] = plan._base_res_row[key]
+        out[name] = args
+    return out
+
+
+def _draws_in_class(plan: Any, res: dict, dat: dict) -> bool:
+    """True when a spec group's fixed, base and ramp inputs fit the batched
+    class (the sign of each resource factor is checked per row)."""
+    if plan._class_reason is not None:
+        return False
+    for inp in res.values():
+        if inp.ramp is not None:      # sampled rates are clipped at 0
+            sampled = {slot for slot, _col in inp.slots}
+            if any(r < 0.0 for k, r in enumerate(inp.ramp.rates)
+                   if k not in sampled):
+                return False
+        elif not is_batchable_resource(inp.fn):
+            return False
+    if not all(inp.fn.is_piecewise_quadratic for inp in dat.values()):
+        return False
+    return (all(ok or k in res for k, ok in plan._base_res_ok.items())
+            and all(ok or k in dat for k, ok in plan._base_data_ok.items()))
+
+
+def _draw_planes(plan: Any, inp: Any, values: Mapping[str, np.ndarray],
+                 draws: np.ndarray) -> BPL:
+    """The planes of one spec-group input in ``draws`` (a single row when
+    every draw has the same function)."""
+    if inp.ramp is not None:
+        t = np.asarray(inp.ramp.times, dtype=np.float64)
+        sampled = dict(inp.slots)
+        rates = np.empty((len(draws), len(t)))
+        for k, r in enumerate(inp.ramp.rates):
+            rates[:, k] = values[sampled[k]][draws] if k in sampled else r
+        c1 = np.zeros_like(rates)
+        c1[:, :-1] = (rates[:, 1:] - rates[:, :-1]) / np.diff(t)
+        return BPL(np.broadcast_to(t, rates.shape), rates, c1 + 0.0)
+    if inp.col is None:
+        return BPL.from_ppolys([inp.fn])
+    f = values[inp.col][draws][:, None]
+    if inp.is_res:                  # rate times f
+        b = plan._base_res_row[inp.key]
+        return BPL(np.broadcast_to(b.starts, (len(draws), b.P)), b.c0 * f,
+                   b.c1 * f + 0.0)
+    # data sped up by f: g(f t), in local coordinates u = t - start / f
+    b = BPL.from_ppolys([inp.fn.simplify()])
+    return BPL(b.starts / f, np.broadcast_to(b.c0, (len(draws), b.P)),
+               b.c1 * f + 0.0, None if b.c2 is None else b.c2 * (f * f) + 0.0)
+
+
+def _stack_rows(B: int, parts: list[tuple[np.ndarray, BPL]]) -> BPL:
+    """One ``(B, P)`` batch from ``(positions, planes)`` parts, padded as
+    :meth:`BPL.from_ppolys` pads (``PAD_START`` starts, zero planes)."""
+    P = max(b.P for _pos, b in parts)
+    starts = np.full((B, P), PAD_START)
+    planes = [np.zeros((B, P))
+              for _ in range(3 if any(b.c2 is not None for _p, b in parts)
+                             else 2)]
+    for pos, b in parts:
+        starts[pos, :b.P] = b.starts
+        for out, a in zip(planes, b.arrays()[1:]):
+            out[pos, :b.P] = a
+    return BPL(starts, *planes)
+
+
+def _pack_draw_args(plan: Any, values: Mapping[str, np.ndarray],
+                    groups: list, B: int,
+                    ) -> dict[str, dict[str, dict[str, BPL]]]:
+    """:func:`_pack_proc_args` for Monte Carlo draws.  ``groups`` holds, per
+    spec group, its ``(res, data)`` inputs, its positions in the batched
+    partition and their draw indices."""
+
+    def base(key: tuple[str, str], is_res: bool) -> BPL:
+        return (plan._base_res_row[key] if is_res
+                else BPL.from_ppolys([plan.base_data[key]]))
+
+    def packed(key: tuple[str, str], is_res: bool) -> BPL | None:
+        """The key's planes where some group sets it, else None."""
+        parts, rest = [], []
+        for (res, dat), pos, draws in groups:
+            inp = (res if is_res else dat).get(key)
+            if inp is None:
+                rest.append(pos)
+            else:
+                parts.append((pos, _draw_planes(plan, inp, values, draws)))
+        if not parts:
+            return None
+        if rest:
+            parts.append((np.concatenate(rest), base(key, is_res)))
+        return _stack_rows(B, parts)
+
+    out: dict[str, dict[str, dict[str, BPL]]] = {}
+    for name in plan.order:
+        proc = plan.workflow.processes[name]
+        args: dict[str, dict[str, BPL]] = {"res": {}, "data": {}, "ceil": {}}
+        edge_deps = {dep for (_s, _o, dep) in plan.edges_in[name]}
+        for dep in proc.data:
+            if dep in edge_deps:
+                continue  # pipelined: composed from upstream progress in-solve
+            key = (name, dep)
+            bpl = packed(key, False)
+            if bpl is not None:
+                args["data"][dep] = bpl
+            elif key in plan._base_ceil_row:
+                args["ceil"][dep] = plan._base_ceil_row[key]
+            else:
+                args["data"][dep] = base(key, False)
+        for r in proc.resources:
+            key = (name, r)
+            args["res"][r] = packed(key, True) or base(key, True)
         out[name] = args
     return out
 

@@ -21,6 +21,7 @@ scalar fallback).
 
 from __future__ import annotations
 
+from collections.abc import Sequence
 from dataclasses import dataclass, field
 from typing import TYPE_CHECKING, Any, Iterable
 
@@ -33,8 +34,8 @@ if TYPE_CHECKING:  # imported lazily at runtime to keep the package acyclic
 
     from .plan import CompiledWorkflow
 
-__all__ = ["BottleneckRow", "FinishTimes", "Report", "concat_reports",
-           "report_from_scalar"]
+__all__ = ["BottleneckRow", "FinishTimes", "LazyScenarios", "Report",
+           "concat_reports", "report_from_scalar"]
 
 
 @dataclass
@@ -69,6 +70,47 @@ def _pack_f32(bpl: Any) -> tuple[np.ndarray, np.ndarray]:
     return bpl.kernel_args()
 
 
+class LazyScenarios(Sequence):
+    """Read-only scenarios of draws ``rows`` of a Monte Carlo sample set,
+    each built by ``source.scenario(j)`` only when it is asked for.
+
+    Slices, index arrays and concatenations of views over one source stay
+    views, so row bookkeeping (:meth:`Report.subset`, :func:`concat_reports`,
+    padding) never builds the per-draw objects.
+    """
+
+    def __init__(self, source: Any, rows: Any):
+        self.source = source
+        self.rows = np.asarray(rows, dtype=np.int64)
+
+    def __len__(self) -> int:
+        return len(self.rows)
+
+    def __getitem__(self, i: Any) -> Any:
+        if isinstance(i, (int, np.integer)):
+            return self.source.scenario(int(self.rows[i]))
+        return LazyScenarios(self.source, self.rows[
+            i if isinstance(i, slice) else np.asarray(i, dtype=np.int64)])
+
+
+def take_rows(scenarios: "Sequence[Scenario]", idx: Any) -> "Sequence[Scenario]":
+    """Rows ``idx`` of a scenario list, or of a :class:`LazyScenarios` view
+    (which stays a view)."""
+    if isinstance(scenarios, LazyScenarios):
+        return scenarios[idx]
+    return [scenarios[i] for i in idx]
+
+
+def _join_scenarios(parts: list) -> "Sequence[Scenario]":
+    first = parts[0]
+    if isinstance(first, LazyScenarios) and all(
+            isinstance(p, LazyScenarios) and p.source is first.source
+            for p in parts):
+        return LazyScenarios(first.source,
+                             np.concatenate([p.rows for p in parts]))
+    return [sc for p in parts for sc in p]
+
+
 @dataclass
 class Report:
     """Unified analysis of one scenario (scalar) or B scenarios (sweep)."""
@@ -84,7 +126,8 @@ class Report:
     proc_results: dict[str, BatchProcResult] | None = None
     scalar_results: dict[str, ProgressResult] | None = None
     plan: CompiledWorkflow | None = field(default=None, repr=False, compare=False)
-    scenarios: list[Scenario] | None = field(default=None, repr=False, compare=False)
+    scenarios: "Sequence[Scenario] | None" = field(default=None, repr=False,
+                                                   compare=False)
     #: scenario index -> why it fell off the batched function class (with
     #: the offending input's degree/shape); None when nothing fell back
     fallback_reasons: dict[int, str] | None = field(
@@ -172,7 +215,7 @@ class Report:
             share_fractions=self.share_fractions[idx],
             backends=[self.backends[i] for i in idx],
             plan=self.plan,
-            scenarios=([self.scenarios[i] for i in idx]
+            scenarios=(take_rows(self.scenarios, idx)
                        if self.scenarios is not None else None),
             fallback_reasons=({j: self.fallback_reasons[int(i)]
                                for j, i in enumerate(idx)
@@ -402,7 +445,6 @@ def concat_reports(reports: "Iterable[Report]") -> Report:
     secs = np.zeros((B, len(factors)))
     fracs = np.zeros((B, len(factors)))
     have_sc = all(r.scenarios is not None for r in reps)
-    scenarios: list[Scenario] = []
     fallback_reasons: dict[int, str] = {}
     off = 0
     for r in reps:
@@ -412,8 +454,6 @@ def concat_reports(reports: "Iterable[Report]") -> Report:
             fracs[off:off + r.B, cols] = r.share_fractions
         for i, why in (r.fallback_reasons or {}).items():
             fallback_reasons[off + int(i)] = why
-        if have_sc:
-            scenarios.extend(r.scenarios)  # type: ignore[arg-type]
         off += r.B
     plan = reps[0].plan
     if any(r.plan is not plan for r in reps):
@@ -426,7 +466,9 @@ def concat_reports(reports: "Iterable[Report]") -> Report:
                             for n in order}),
         factors=factors, share_seconds=secs, share_fractions=fracs,
         backends=[b for r in reps for b in r.backends],
-        plan=plan, scenarios=scenarios if have_sc else None,
+        plan=plan,
+        scenarios=(_join_scenarios([r.scenarios for r in reps])
+                   if have_sc else None),
         fallback_reasons=fallback_reasons or None,
         engine_fallback=next(
             (r.engine_fallback for r in reps if r.engine_fallback), None))

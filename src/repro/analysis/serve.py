@@ -210,6 +210,11 @@ class ServiceStats:
     #: seconds from submit to the start of each request's own sweep, summed
     queue_wait_s: float = 0.0
     queue_waits: int = 0       #: requests ``queue_wait_s`` sums over
+    #: Monte Carlo rows the worker packed straight from factor arrays
+    mc_draws_direct: int = 0
+    #: :class:`Scenario` objects built from Monte Carlo draws (loop-routed
+    #: rows, or asked for through ``MCReport.scenarios``)
+    mc_draws_materialized: int = 0
     latencies_s: deque = field(default_factory=lambda: deque(maxlen=4096))
 
     def latency_quantiles(self, qs: Sequence[float] = (0.5, 0.99)
@@ -269,6 +274,8 @@ class ServiceStats:
             "latency_p50_s": p50, "latency_p99_s": p99,
             "queue_wait_s": self.queue_wait_s,
             "queue_waits": self.queue_waits,
+            "mc_draws_direct": self.mc_draws_direct,
+            "mc_draws_materialized": self.mc_draws_materialized,
         }
 
 
@@ -280,6 +287,7 @@ class _Request:
     id: int                            # shared by the chunks of one submit_mc
     scenarios: list | None = None      # coalescable what-if query
     pack: ScenarioPack | None = None   # pre-packed (online re-analysis)
+    draws: tuple | None = None         # Monte Carlo chunk (samples, lo, hi)
     optimize: dict | None = None       # plan.optimize kwargs (solo request)
     deadline: float | None = None      # absolute perf_counter() deadline
     retries: int = 0                   # backoff retries already spent
@@ -287,6 +295,17 @@ class _Request:
 
     def expired(self, now: float) -> bool:
         return self.deadline is not None and now > self.deadline
+
+    @property
+    def rows(self) -> int:
+        """Scenario rows this request asks for."""
+        if self.scenarios is not None:
+            return len(self.scenarios)
+        if self.pack is not None:
+            return self.pack.B
+        if self.draws is not None:
+            return self.draws[2] - self.draws[1]
+        return 1
 
 
 def _pow2_bucket(b: int) -> int:
@@ -623,13 +642,15 @@ class AnalysisService:
     def _make_request(self, plan: CompiledWorkflow, *,
                       scenarios: list | None = None,
                       pack: ScenarioPack | None = None,
+                      draws: tuple | None = None,
                       optimize: dict | None = None,
                       deadline_s: float | None = None,
                       req_id: int | None = None) -> _Request:
         now = time.perf_counter()
         return _Request(plan=plan, future=Future(), t_submit=now,
                         id=next(self._ids) if req_id is None else req_id,
-                        scenarios=scenarios, pack=pack, optimize=optimize,
+                        scenarios=scenarios, pack=pack, draws=draws,
+                        optimize=optimize,
                         deadline=(None if deadline_s is None
                                   else now + float(deadline_s)))
 
@@ -651,9 +672,7 @@ class AnalysisService:
                     req.scenarios = self._faults.corrupt_request(
                         self.stats.requests, req.scenarios)
                 self._queue.append(req)
-                self.stats.scenarios += (
-                    len(req.scenarios) if req.scenarios is not None
-                    else req.pack.B if req.pack is not None else 1)
+                self.stats.scenarios += req.rows
             self._wake.notify()
         return [req.future for req in reqs]
 
@@ -722,10 +741,14 @@ class AnalysisService:
 
         The ``n`` draws are sampled host-side immediately (same deterministic
         sampler as ``plan.mc`` — identical ``seed`` gives bit-identical
-        scenarios) and enqueued in ``max_batch``-sized chunks as ordinary
-        coalescable requests, so probabilistic queries ride the same worker,
-        plan cache, and fused XLA traces as the what-if traffic — and batch
-        WITH it.  Chunk reports are stitched back together with
+        draws) and enqueued in ``max_batch``-sized chunks that carry the
+        factor arrays, not scenarios: the worker packs each chunk straight
+        from them (:meth:`ScenarioPack.from_draws`, padded to its pow2
+        bucket), on the same plan cache and fused XLA traces as the what-if
+        traffic.  The chunks share one request id and do not coalesce with
+        what-if requests: a large call fills ``max_batch`` chunks, so only
+        its tail chunk could ever share a sweep.  Chunk reports are
+        stitched back together with
         :func:`~repro.analysis.report.concat_reports` when the last chunk
         lands.  The chunks are admitted atomically (one :class:`Overloaded`
         rejects the whole query), and the aggregate future ALWAYS resolves:
@@ -742,8 +765,9 @@ class AnalysisService:
         rid = next(self._ids)
         with TraceAnnotation("bm.mc.sample", req=rid):
             samples = sample_spec(plan, spec, n, seed=seed)
+        samples.on_build = self._count_built
         reqs = [self._make_request(
-                    plan, scenarios=samples.scenarios[lo:lo + chunk_w],
+                    plan, draws=(samples, lo, min(lo + chunk_w, n)),
                     deadline_s=deadline_s, req_id=rid)
                 for lo in range(0, n, chunk_w)]
         chunk_futs = self._enqueue_many(reqs)
@@ -951,12 +975,15 @@ class AnalysisService:
             reqs = groups[key]
             plan = reqs[0].plan
             packs = [r for r in reqs if r.pack is not None]
+            draws = [r for r in reqs if r.draws is not None]
             opts = [r for r in reqs if r.optimize is not None]
             coalescable = [r for r in reqs if r.scenarios is not None]
             for req in opts:
                 self._run_optimize(plan, req)
             for req in packs:
                 self._sweep_pack(plan, req)
+            for req in draws:
+                self._sweep_draws(plan, req)
             chunk: list[_Request] = []
             width = 0
             for req in coalescable:
@@ -1097,6 +1124,32 @@ class AnalysisService:
             self._retry_or_fail(plan, req, e,
                                 lambda: self._sweep_pack(plan, req))
             return
+        self._finish(req, rep)
+
+    def _count_built(self) -> None:
+        with self._lock:
+            self.stats.mc_draws_materialized += 1
+
+    def _sweep_draws(self, plan: CompiledWorkflow, req: _Request) -> None:
+        """One Monte Carlo chunk, packed from its factor arrays and padded
+        to its pow2 bucket (capped by ``max_batch``)."""
+        self._count_queue_wait([req])
+        samples, lo, hi = req.draws
+        B = hi - lo
+        try:
+            with TraceAnnotation("bm.sweep", req=req.id, n_req=1, rows=B):
+                pack = ScenarioPack.from_draws(
+                    plan, samples, np.arange(lo, hi),
+                    pad_to=(min(_pow2_bucket(B), self.max_batch)
+                            if self.pad_pow2 else None))
+                rep = self._do_sweep(plan, pack, B).subset(range(B))
+        except Exception as e:  # noqa: BLE001 — fail THIS request only
+            self._retry_or_fail(plan, req, e,
+                                lambda: self._sweep_draws(plan, req))
+            return
+        with self._lock:
+            self.stats.mc_draws_direct += B - sum(i < B for i in pack.loop_idx)
+            self.stats.max_batch_B = max(self.stats.max_batch_B, B)
         self._finish(req, rep)
 
     def _sweep_chunk(self, plan: CompiledWorkflow,
@@ -1340,14 +1393,9 @@ class OnlineReanalysis:
         themselves scale the plan's base inputs.  With a service attached the
         fused sweep runs on its worker, sharing traces with live traffic.
         """
-        samples = sample_spec(self.plan, spec, n, seed=seed)
-        base = self.pack.scenarios[template]
-        for sc in samples.scenarios:
-            for k, fn in base.resource_inputs.items():
-                sc.resource_inputs.setdefault(k, fn)
-            for k, fn in base.data_inputs.items():
-                sc.data_inputs.setdefault(k, fn)
-        pack = self.plan.prepare(samples.scenarios)
+        samples = sample_spec(self.plan, spec, n, seed=seed).around(
+            self.pack.scenarios[template])
+        pack = ScenarioPack.from_draws(self.plan, samples)
         if self._service is not None:
             rep = self._service.submit_pack(pack).result()
         else:

@@ -13,9 +13,10 @@ already shards and jits is exactly a Monte Carlo axis:
   host-side into 53-bit uniforms and inverse-transformed in numpy float64,
   so a seeded run is bit-reproducible across runs, JAX x64 state, and
   ``shard(n)`` device counts.
-* :func:`run_mc` — ``plan.mc(spec, n, seed)``: sample, pack through the
-  existing :class:`~repro.analysis.pack.ScenarioPack` path, sweep fused,
-  wrap in an :class:`MCReport`.
+* :func:`run_mc` — ``plan.mc(spec, n, seed)``: sample, pack the factor
+  arrays straight into sweep planes
+  (:meth:`~repro.analysis.pack.ScenarioPack.from_draws`: no
+  :class:`Scenario` per draw), sweep fused, wrap in an :class:`MCReport`.
 * :class:`MCReport` — makespan quantiles (``p50/p95/p99``), SLO queries
   (:meth:`MCReport.prob`), per-factor **bottleneck-attribution
   probabilities** ("dl2.link binds in 83 % of draws", derived from the
@@ -28,20 +29,20 @@ already shards and jits is exactly a Monte Carlo axis:
 from __future__ import annotations
 
 import warnings
-from dataclasses import dataclass, field
-from typing import Any, Mapping, Sequence
+from dataclasses import dataclass, field, replace
+from typing import Any, Callable, Mapping, Sequence
 
 import numpy as np
-from jax.profiler import TraceAnnotation
 
 from repro.core.ppoly import PPoly
 from repro.sweep.batch import Scenario
 
-from .report import Report
+from .pack import ScenarioPack
+from .report import LazyScenarios, Report
 from .scenarios import (Dist, DistRamp, ScenarioSpec, override, parse_key,
                         speed_up_data)
 
-__all__ = ["MCAttribution", "MCAxis", "MCReport", "MCSamples",
+__all__ = ["DrawInput", "MCAttribution", "MCAxis", "MCReport", "MCSamples",
            "MCSensitivity", "mc_report_from_sweep", "run_mc", "sample_spec"]
 
 #: default quantile levels reported by MCReport.quantiles()
@@ -72,12 +73,55 @@ class MCAxis:
         return f"{base}[t={self.slot_time:g}]"
 
 
+@dataclass(frozen=True)
+class DrawInput:
+    """How one input of a spec group is set in every draw of the group.
+
+    ``fn`` alone is a fixed function.  ``fn`` with ``col`` is a base
+    function scaled by the factor column ``col`` of
+    :attr:`MCSamples.values`: a rate multiplier for a resource, a time-axis
+    speed-up for a data input.  ``ramp`` interpolates its rates over its
+    times, reading each sampled slot from the ``(slot, column)`` pairs of
+    ``slots``.
+    """
+
+    proc: str
+    name: str
+    is_res: bool
+    fn: PPoly | None = None
+    col: str | None = None
+    ramp: DistRamp | None = None
+    slots: tuple = ()
+
+    @property
+    def key(self) -> tuple[str, str]:
+        return (self.proc, self.name)
+
+    def function(self, values: Mapping[str, np.ndarray], j: int) -> PPoly:
+        """This input's function in draw ``j``."""
+        if self.ramp is not None:
+            rates = [0.0 if isinstance(r, Dist) else r
+                     for r in self.ramp.rates]
+            for slot, col in self.slots:
+                rates[slot] = float(values[col][j])
+            return PPoly.pwlinear(list(self.ramp.times), rates)
+        if self.col is None:
+            return self.fn
+        f = float(values[self.col][j])
+        return self.fn * f if self.is_res else speed_up_data(self.fn, f)
+
+
 @dataclass
 class MCSamples:
-    """The materialized draw set: concrete scenarios + the factor arrays that
-    produced them (the evidence the sensitivity indices correlate against)."""
+    """The draw set: the factor arrays per sampled axis (the evidence the
+    sensitivity indices correlate against) and, per spec group, how every
+    input of a draw follows from them.
 
-    scenarios: list[Scenario]
+    The Monte Carlo sweeps pack the factor arrays directly
+    (:meth:`ScenarioPack.from_draws`); :attr:`scenarios` builds a draw's
+    :class:`Scenario` only when it is asked for.
+    """
+
     axes: list[MCAxis]
     values: dict[str, np.ndarray]        # axis label -> (n,) float64
     seed: int
@@ -85,6 +129,41 @@ class MCSamples:
     group_of: np.ndarray                 # (n,) spec-group index
     group_labels: list[str]
     labels: list[str]                    # per-draw scenario labels
+    #: per spec group, its inputs in a draw's dict order (later entries win)
+    inputs: list[list[DrawInput]] = field(default_factory=list, repr=False)
+    #: called once per :class:`Scenario` built from a draw
+    on_build: Callable[[], None] | None = field(default=None, repr=False)
+
+    @property
+    def scenarios(self) -> LazyScenarios:
+        """Every draw as a read-only, lazily built :class:`Scenario`."""
+        return LazyScenarios(self, np.arange(self.n))
+
+    def scenario(self, j: int) -> Scenario:
+        """Draw ``j`` as a :class:`Scenario`."""
+        res_in: dict[tuple[str, str], PPoly] = {}
+        dat_in: dict[tuple[str, str], PPoly] = {}
+        for inp in self.inputs[int(self.group_of[j])]:
+            (res_in if inp.is_res else dat_in)[inp.key] = \
+                inp.function(self.values, j)
+        if self.on_build is not None:
+            self.on_build()
+        return Scenario(label=self.labels[j], resource_inputs=res_in,
+                        data_inputs=dat_in)
+
+    def around(self, template: Scenario) -> "MCSamples":
+        """These draws, with every input they leave unset taken from
+        ``template`` (a tracked state, as in :meth:`OnlineReanalysis.mc`)."""
+        extra = ([DrawInput(p, n, True, fn)
+                  for (p, n), fn in template.resource_inputs.items()]
+                 + [DrawInput(p, n, False, fn)
+                    for (p, n), fn in template.data_inputs.items()])
+
+        def fill(group: list[DrawInput]) -> list[DrawInput]:
+            have = {(inp.key, inp.is_res) for inp in group}
+            return group + [x for x in extra if (x.key, x.is_res) not in have]
+
+        return replace(self, inputs=[fill(g) for g in self.inputs])
 
 
 # ---------------------------------------------------------------------------
@@ -187,10 +266,10 @@ def sample_spec(plan: Any, spec: Any, n: int, *args,
 
     all_axes: list[MCAxis] = []
     values: dict[str, np.ndarray] = {}
-    scenarios_out: list[Scenario] = []
-    labels: list[str] = []
+    inputs: list[list[DrawInput]] = []
 
     for g, (sp, ng) in enumerate(zip(specs, counts)):
+        inputs.append([])
         if ng == 0:
             continue
         gkey = jax.random.fold_in(root, g)
@@ -208,17 +287,19 @@ def sample_spec(plan: Any, spec: Any, n: int, *args,
         entries.sort(key=lambda e: (e[0], e[1], not e[2]))
 
         axes_g: list[tuple[MCAxis, np.ndarray]] = []
-        fixed_fns: dict[tuple[str, str, bool], PPoly] = {}
-        ramp_templates: dict[tuple[str, str], DistRamp] = {}
+        fixed: dict[tuple[str, str, bool], DrawInput] = {}
+        factors: list[DrawInput] = []
+        ramps: list[DrawInput] = []
         axis_i = 0
         for proc, name, is_res, v in entries:
-            key = (proc, name)
             if isinstance(v, DistRamp):
                 if not is_res:
                     raise ValueError(
                         f"mc: {proc}.{name} — DistRamp values describe "
                         "resource rate ramps, not data inputs")
-                ramp_templates[key] = v
+                # refuse times that make no function, as each draw would
+                PPoly.pwlinear(list(v.times), [0.0] * len(v.times))
+                slots = []
                 for slot in v.dist_slots():
                     ax = MCAxis(proc, name, "resource", v.rates[slot],
                                 slot=slot, slot_time=v.times[slot])
@@ -226,20 +307,36 @@ def sample_spec(plan: Any, spec: Any, n: int, *args,
                                    ax.dist.n_uniforms)
                     # in-class guarantee: resource rates must be >= 0
                     axes_g.append((ax, np.maximum(ax.dist.sample(u), 0.0)))
+                    slots.append((slot, ax.label))
                     axis_i += 1
+                if slots:
+                    ramps.append(DrawInput(proc, name, True, ramp=v,
+                                           slots=tuple(slots)))
             elif isinstance(v, Dist):
                 ax = MCAxis(proc, name, "resource" if is_res else "data", v)
                 u = _uniform01(jax.random.fold_in(gkey, axis_i), ng,
                                v.n_uniforms)
-                axes_g.append((ax, v.sample(u)))
+                vals = v.sample(u)
+                if not is_res and (bad := np.flatnonzero(vals <= 0.0)).size:
+                    raise ValueError(
+                        f"mc: draw {int(bad[0])} sampled non-positive data "
+                        f"speed-up {float(vals[bad[0]]):g} for {ax.label}; "
+                        "data-input factor distributions must have positive "
+                        "support")
+                axes_g.append((ax, vals))
+                factors.append(DrawInput(proc, name, is_res,
+                                         _base_fn(plan, proc, name, is_res),
+                                         col=ax.label))
                 axis_i += 1
             elif isinstance(v, PPoly):
-                fixed_fns[(proc, name, is_res)] = v
+                fixed[(proc, name, is_res)] = DrawInput(proc, name, is_res, v)
             else:   # plain number: same resolution rule as ScenarioSpec
                 base = _base_fn(plan, proc, name, is_res)
-                fixed_fns[(proc, name, is_res)] = (
+                fixed[(proc, name, is_res)] = DrawInput(
+                    proc, name, is_res,
                     base * float(v) if is_res
                     else speed_up_data(base, float(v)))
+        inputs[g] = list(fixed.values()) + factors + ramps
 
         lo = int(np.searchsorted(group_of, g, side="left"))
         for ax, vals in axes_g:
@@ -247,52 +344,11 @@ def sample_spec(plan: Any, spec: Any, n: int, *args,
             col = values.setdefault(ax.label, np.full(n, np.nan))
             col[lo:lo + ng] = vals
 
-        # materialize one concrete Scenario per draw
-        with TraceAnnotation("bm.mc.materialize"):
-            factor_axes = [(ax, vals) for ax, vals in axes_g
-                           if ax.slot is None]
-            ramp_axes: dict[tuple[str, str], list[tuple[int, np.ndarray]]] = {}
-            for ax, vals in axes_g:
-                if ax.slot is not None:
-                    ramp_axes.setdefault((ax.proc, ax.name), []).append(
-                        (ax.slot, vals))
-            base_of = {(ax.proc, ax.name): _base_fn(plan, ax.proc, ax.name,
-                                                    ax.kind == "resource")
-                       for ax, _ in factor_axes}
-            for i in range(ng):
-                res_in: dict[tuple[str, str], PPoly] = {}
-                dat_in: dict[tuple[str, str], PPoly] = {}
-                for (proc, name, is_res), fn in fixed_fns.items():
-                    (res_in if is_res else dat_in)[(proc, name)] = fn
-                for ax, vals in factor_axes:
-                    base = base_of[(ax.proc, ax.name)]
-                    f = float(vals[i])
-                    if ax.kind == "resource":
-                        res_in[(ax.proc, ax.name)] = base * f
-                    else:
-                        if f <= 0.0:
-                            raise ValueError(
-                                f"mc: draw {i} sampled non-positive data "
-                                f"speed-up {f:g} for {ax.label}; data-input "
-                                "factor distributions must have positive "
-                                "support")
-                        dat_in[(ax.proc, ax.name)] = speed_up_data(base, f)
-                for (proc, name), slots in ramp_axes.items():
-                    tpl = ramp_templates[(proc, name)]
-                    rates = [r if not isinstance(r, Dist) else 0.0
-                             for r in tpl.rates]
-                    for slot, vals in slots:
-                        rates[slot] = float(vals[i])
-                    res_in[(proc, name)] = PPoly.pwlinear(list(tpl.times),
-                                                          rates)
-                scenarios_out.append(Scenario(
-                    label=f"{group_labels[g]}#{i}",
-                    resource_inputs=res_in, data_inputs=dat_in))
-                labels.append(f"{group_labels[g]}#{i}")
-
-    return MCSamples(scenarios=scenarios_out, axes=all_axes, values=values,
-                     seed=int(seed), n=n, group_of=group_of,
-                     group_labels=group_labels, labels=labels)
+    labels = [f"{group_labels[g]}#{i}"
+              for g, ng in enumerate(counts) for i in range(ng)]
+    return MCSamples(axes=all_axes, values=values, seed=int(seed), n=n,
+                     group_of=group_of, group_labels=group_labels,
+                     labels=labels, inputs=inputs)
 
 
 def _base_fn(plan: Any, proc: str, name: str, is_res: bool) -> PPoly:
@@ -404,7 +460,7 @@ class MCReport:
         return self.report.makespans
 
     @property
-    def scenarios(self) -> list[Scenario] | None:
+    def scenarios(self) -> Sequence[Scenario] | None:
         return self.report.scenarios
 
     # -- quantiles + SLO queries --------------------------------------------
@@ -567,7 +623,7 @@ class MCReport:
 def mc_report_from_sweep(rep: Report, samples: MCSamples,
                          quantile_levels: Sequence[float] = DEFAULT_QUANTILES,
                          ) -> MCReport:
-    """Wrap an already-run sweep of ``samples.scenarios`` into an
+    """Wrap an already-run sweep of ``samples``' draws into an
     :class:`MCReport` (also the numpy-oracle entry point for tests)."""
     if rep.B != samples.n:
         raise ValueError(f"report has {rep.B} rows for {samples.n} draws")
@@ -600,14 +656,15 @@ def run_mc(plan: Any, spec: Any, n: int = 10_000, *, seed: int = 0,
            quantile_levels: Sequence[float] = DEFAULT_QUANTILES) -> MCReport:
     """Sample ``n`` draws of ``spec`` and analyze them as one fused sweep.
 
-    The backing :meth:`CompiledWorkflow.sweep` call goes through the normal
-    prepared-pack path (``backend="auto"`` routes the batched partition to
-    the fused jax engine); ``shards`` optionally pmap-shards the draw axis.
+    The draws are packed from their factor arrays
+    (:meth:`ScenarioPack.from_draws`) and swept as a prepared pack
+    (``backend="auto"`` routes the batched partition to the fused jax
+    engine); ``shards`` optionally pmap-shards the draw axis.
     Warnings: at most ONE fallback warning fires per call, carrying the
     aggregate off-class rate, however many draws fell back.
     """
     samples = sample_spec(plan, spec, n, seed=seed)
-    pack = plan.prepare(samples.scenarios)
+    pack = ScenarioPack.from_draws(plan, samples)
     if shards is not None and int(shards) > 1:
         pack = pack.shard(int(shards))
     with warnings.catch_warnings(record=True) as caught:

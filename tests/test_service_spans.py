@@ -97,7 +97,7 @@ def inside(child, parent) -> bool:
 
 def test_span_names_are_the_documented_set(served):
     doc = documented_spans()
-    assert len(doc) == 10, doc
+    assert len(doc) == 9, doc
     seen = {s[0] for kind in served for s in served[kind][4]}
     assert seen == doc
 
@@ -105,18 +105,29 @@ def test_span_names_are_the_documented_set(served):
 @pytest.mark.parametrize("child,parent", [
     ("bm.pack", "bm.sweep"), ("bm.engine.stage", "bm.sweep"),
     ("bm.engine.put", "bm.sweep"), ("bm.engine.call", "bm.sweep"),
-    ("bm.engine.fetch", "bm.sweep"), ("bm.report", "bm.sweep"),
-    ("bm.mc.materialize", "bm.mc.sample")])
+    ("bm.engine.fetch", "bm.sweep"), ("bm.report", "bm.sweep")])
 def test_spans_nest_by_layer(served, child, parent):
     for kind in served:
         sp = served[kind][4]
         kids = [s for s in sp if s[0] == child]
-        if child.startswith("bm.mc.") and kind != "mc":
-            assert not kids
-            continue
         assert kids, (kind, child)
         parents = [s for s in sp if s[0] == parent]
         assert all(any(inside(c, p) for p in parents) for c in kids)
+
+
+def test_mc_pack_inside_the_sweep_of_its_chunk(served):
+    """The draws are packed on the worker, one ``bm.pack`` inside each
+    chunk's ``bm.sweep`` (whose ``rows`` is the chunk), never on the
+    caller's thread."""
+    sp = served["mc"][4]
+    packs = [s for s in sp if s[0] == "bm.pack"]
+    sweeps = [s for s in sp if s[0] == "bm.sweep"]
+    assert len(packs) == len(sweeps) == -(-N_DRAWS // CHUNK)
+    for pk in packs:
+        parent, = [s for s in sweeps if inside(pk, s)]
+        assert parent[4]["rows"] == CHUNK
+    caller = next(s[3] for s in sp if s[0] == "bm.mc.sample")
+    assert all(pk[3] != caller for pk in packs)
 
 
 @pytest.mark.parametrize("kind", ["mc", "whatif"])
