@@ -215,6 +215,9 @@ class ServiceStats:
     #: :class:`Scenario` objects built from Monte Carlo draws (loop-routed
     #: rows, or asked for through ``MCReport.scenarios``)
     mc_draws_materialized: int = 0
+    #: lockstep steps of the fused engine: per sweep, the sum over topology
+    #: levels of that level's loop trip count
+    engine_level_steps: int = 0
     latencies_s: deque = field(default_factory=lambda: deque(maxlen=4096))
 
     def latency_quantiles(self, qs: Sequence[float] = (0.5, 0.99)
@@ -276,6 +279,7 @@ class ServiceStats:
             "queue_waits": self.queue_waits,
             "mc_draws_direct": self.mc_draws_direct,
             "mc_draws_materialized": self.mc_draws_materialized,
+            "engine_level_steps": self.engine_level_steps,
         }
 
 
@@ -1010,12 +1014,22 @@ class AnalysisService:
         """One guarded fused sweep + fault hooks + the degradation guard."""
         if self._faults is not None:
             self._faults.before_sweep()
+        steps0 = self._level_steps(plan)
         rep = plan.sweep(pack, backend=self.backend)
+        steps = self._level_steps(plan) - steps0
         with self._lock:
             self.stats.sweeps += 1
+            self.stats.engine_level_steps += steps
         if self._faults is not None:
             rep = self._faults.after_sweep(rep)
         return self._degrade_guard(plan, pack, rep, B_real)
+
+    @staticmethod
+    def _level_steps(plan: CompiledWorkflow) -> int:
+        """Lockstep steps the plan's fused engine has run (one worker sweeps,
+        so the delta around a sweep is that sweep's)."""
+        eng = plan._jax_engine
+        return eng.level_steps if eng is not None else 0
 
     def _degrade_guard(self, plan: CompiledWorkflow, pack: ScenarioPack,
                        rep: Report, B_real: int) -> Report:

@@ -175,19 +175,21 @@ def solve_batch(proc: Process, data_bpls: dict[str, BPL],
             V = np.stack([x[0] for x in VSQ])                    # (nC, B)
             S = np.stack([x[1] for x in VSQ])
             Qc = np.stack([x[2] for x in VSQ])
-            # ties on value break on slope, then curvature (the function that
-            # is lower just after t governs the piece — the scalar minimum's
-            # midpoint rule, resolved one derivative at a time)
-            vtie = V <= V.min(0) + VAL_RTOL * np.maximum(1.0, np.abs(V.min(0)))
-            St = np.where(vtie, S, _INF)
-            Smin = St.min(0)
-            stie = vtie & (St <= Smin + VAL_RTOL * np.maximum(1.0, np.abs(Smin)))
-            kstar = np.where(stie, Qc, _INF).argmin(0)
         else:
             V = np.stack([c.eval_right(t) for c in ceils])       # (nC, B)
             S = np.stack([c.slope_right(t) for c in ceils])
             Qc = None
-            kstar = V.argmin(0)                                  # ties -> low k
+        # ties on value break on slope, then curvature, then low k (the
+        # function that is lower just after t governs the piece — the scalar
+        # minimum's midpoint rule, resolved one derivative at a time)
+        Vmin = V.min(0)
+        tie = V <= Vmin + VAL_RTOL * np.maximum(1.0, np.abs(Vmin))
+        St = np.where(tie, S, _INF)
+        if ramp:
+            Smin = St.min(0)
+            tie &= St <= Smin + VAL_RTOL * np.maximum(1.0, np.abs(Smin))
+            St = np.where(tie, Qc, _INF)
+        kstar = St.argmin(0)
         pd = V[kstar, arangeB]
         pdslope = S[kstar, arangeB]
         pdq = Qc[kstar, arangeB] if ramp else _zeros

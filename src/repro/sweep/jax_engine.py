@@ -545,32 +545,30 @@ def _solve_level(ls: _LevelSpec, C, IR, t0, B: int, iter_cap: int,
             Q = _gather(C[3], iC)
             V = _gather(C[1], iC) + (slC + Q * uC) * uC             # (nC,Lp,B)
             S = slC + 2.0 * Q * uC
-            if nC > 1:
-                # value ties break on slope, then curvature: the ceiling that
-                # is lower just after t governs (mirrors the numpy twin)
-                vmin = V.min(0)
-                vtie = V <= vmin + VAL_RTOL * jnp.maximum(1.0, jnp.abs(vmin))
-                St = jnp.where(vtie, S, _INF)
-                Smin = St.min(0)
-                stie = vtie & (St <= Smin + VAL_RTOL * jnp.maximum(
-                    1.0, jnp.abs(Smin)))
-                kstar = jnp.argmin(jnp.where(stie, Q, _INF), 0).astype(jnp.int32)
-                pd = jnp.take_along_axis(V, kstar[None], 0)[0]
-                pdslope = jnp.take_along_axis(S, kstar[None], 0)[0]
-                pdq = jnp.take_along_axis(Q, kstar[None], 0)[0]
-            else:
-                kstar = jnp.zeros((Lp, B), jnp.int32)
-                pd, pdslope, pdq = V[0], S[0], Q[0]
         else:
             V = _gather(C[1], iC) + slC * uC                        # (nC,Lp,B)
             S = slC
-            if nC > 1:
-                kstar = jnp.argmin(V, 0)
-                pd = jnp.take_along_axis(V, kstar[None], 0)[0]
-                pdslope = jnp.take_along_axis(S, kstar[None], 0)[0]
-            else:
-                kstar = jnp.zeros((Lp, B), jnp.int32)
-                pd, pdslope = V[0], S[0]
+        if nC > 1:
+            # value ties break on slope, then curvature: the ceiling that is
+            # lower just after t governs (mirrors the numpy twin)
+            vmin = V.min(0)
+            tie = V <= vmin + VAL_RTOL * jnp.maximum(1.0, jnp.abs(vmin))
+            St = jnp.where(tie, S, _INF)
+            if ramps:
+                Smin = St.min(0)
+                tie = tie & (St <= Smin + VAL_RTOL * jnp.maximum(
+                    1.0, jnp.abs(Smin)))
+                St = jnp.where(tie, Q, _INF)
+            kstar = jnp.argmin(St, 0).astype(jnp.int32)
+            pd = jnp.take_along_axis(V, kstar[None], 0)[0]
+            pdslope = jnp.take_along_axis(S, kstar[None], 0)[0]
+            if ramps:
+                pdq = jnp.take_along_axis(Q, kstar[None], 0)[0]
+        else:
+            kstar = jnp.zeros((Lp, B), jnp.int32)
+            pd, pdslope = V[0], S[0]
+            if ramps:
+                pdq = Q[0]
         tb_ceil = nbC.min(0)
 
         # ---- resource caps and next requirement breakpoints ----------------
@@ -1059,6 +1057,9 @@ class JaxSweepEngine:
         self.trace_count = 0
         #: solves served by an AOT executable adopted from a plan artifact
         self.aot_hits = 0
+        #: lockstep steps run: per solve, the sum over topology levels of
+        #: the level's loop trip count
+        self.level_steps = 0
         #: call-signature census per (B, shards, iter_cap, ramps): the input
         #: aval pytrees actually solved, recorded so :meth:`export_entries`
         #: AOT-serializes exactly the executables a warm start will need
@@ -1415,8 +1416,13 @@ class JaxSweepEngine:
             if cache is not None:
                 cache[key] = dev
         pkey = (Bp, shards, ramps)
-        first = pkey not in self._proven_caps
-        cap = self._proven_caps.get(pkey, self.iter_cap)
+        # a new batch size starts at the largest budget proven for another
+        # (event depth is the workflow's more than the batch's): one compile,
+        # no ladder, and no down-ratchet to recompile for
+        known = [c for (_b, sh, r), c in self._proven_caps.items()
+                 if (sh, r) == (shards, ramps)]
+        first = not known
+        cap = self._proven_caps.get(pkey, max(known, default=self.iter_cap))
         while True:
             # one ladder step: dispatch, the wait for the device, and the
             # overflow flag's readback
@@ -1447,7 +1453,11 @@ class JaxSweepEngine:
             cap = min(cap, 1 << max(actual - 1, 0).bit_length())
         self._proven_caps[pkey] = cap
         with TraceAnnotation("bm.engine.fetch"):
-            return self._wrap(out, B, shards, scenario_ids)
+            results = self._wrap(out, B, shards, scenario_ids)
+        # every process of a level reports the level's trip count
+        self.level_steps += sum(results[ls.procs[0].name].iterations
+                                for ls in self.spec.levels)
+        return results
 
     def _wrap(self, out, B: int, shards: int,
               scenario_ids: list[int] | None = None,

@@ -223,3 +223,23 @@ def test_proven_cap_down_ratchets_once():
     np.testing.assert_array_equal(r1.makespans, r2.makespans)
     np.testing.assert_array_equal(r1.share_seconds, r2.share_seconds)
     assert plan._jax_engine._proven_caps[(4, 1, False)] == cap  # stable
+
+
+def test_new_batch_size_starts_at_a_proven_cap():
+    """A batch size the engine has not solved starts at the budget proven
+    for another one: one compile, no ladder, the same rows as the numpy
+    engine."""
+    from repro.sweep.jax_engine import JaxSweepEngine
+
+    plan = build_workflow(0.5).compile()
+    plan._jax_engine = eng = JaxSweepEngine(plan, iter_cap=1)
+    plan.sweep(plan.prepare(sweep_scenarios(np.linspace(0.1, 0.9, 4))),
+               backend="jax")
+    cap = eng._proven_caps[(4, 1, False)]
+    assert cap > 1 and len(eng._compiled) >= 2     # climbed from 1
+    n = len(eng._compiled)
+    scs = sweep_scenarios(np.linspace(0.2, 0.8, 2))
+    rj = plan.sweep(plan.prepare(scs), backend="jax")
+    assert eng._proven_caps[(2, 1, False)] <= cap
+    assert len(eng._compiled) == n + 1 and (2, 1, cap, False) in eng._compiled
+    _assert_match(rj, plan.sweep(scs, backend="numpy"))

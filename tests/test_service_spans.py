@@ -37,6 +37,15 @@ def documented_spans() -> set:
     return set(re.findall(r"^\| `(bm\.[a-z.]+)` \|", text, re.M))
 
 
+def documented_counters() -> set:
+    """The ``ServiceStats`` counters of the same table: the names in the
+    first column of its rows that are not spans."""
+    text = (ROOT / "PERF.md").read_text()
+    table = text.split("### Spans and counters", 1)[1].split("\n## ", 1)[0]
+    cells = re.findall(r"^\| ((?:`[a-z_]+`(?:, )?)+) \|", table, re.M)
+    return {n for c in cells for n in re.findall(r"`([a-z_]+)`", c)}
+
+
 def serve_mc(plan):
     with AnalysisService(plan, backend="jax", max_batch=CHUNK) as svc:
         s0 = svc.snapshot()
@@ -73,10 +82,14 @@ def spans(trace_dir: str) -> list:
 
 
 @pytest.fixture(scope="module")
-def served(tmp_path_factory):
+def plan():
+    return build_workflow(0.5).compile()
+
+
+@pytest.fixture(scope="module")
+def served(plan, tmp_path_factory):
     """``{kind: (untraced reports, traced reports, stats before, stats
     after, spans)}`` for ``mc`` and ``whatif``."""
-    plan = build_workflow(0.5).compile()
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     out = {}
@@ -167,3 +180,30 @@ def test_profiler_leaves_results_bit_identical(served, kind):
         np.testing.assert_array_equal(a.share_seconds, b.share_seconds)
         for n in a.order:
             np.testing.assert_array_equal(a.finish[n], b.finish[n])
+
+
+def test_documented_counters_are_served(served):
+    doc = documented_counters()
+    assert doc == {"queue_wait_s", "queue_waits", "mc_draws_direct",
+                   "mc_draws_materialized", "engine_level_steps"}
+    for kind in served:
+        assert doc <= set(served[kind][3])
+
+
+def test_level_steps_sum_each_sweeps_level_loops(plan, served):
+    """``engine_level_steps`` grows once per fused sweep by the sum of its
+    levels' loop trip counts: not per process, not per row."""
+    _plain, _reps, s0, s1, _sp = served["whatif"]
+    eng = plan._jax_engine
+    steps0 = eng.level_steps
+    rep = plan.sweep(plan.prepare([sc for scs in WHATIF for sc in scs]),
+                     backend="jax")
+    want = sum(rep.proc_results[lv[0]].iterations for lv in plan.levels)
+    assert len(plan.levels) == 3 and want > 3
+    assert eng.level_steps - steps0 == want
+    assert s1["engine_level_steps"] - s0["engine_level_steps"] == want
+    _plain, _reps, s0, s1, _sp = served["mc"]
+    sweeps = s1["sweeps"] - s0["sweeps"]
+    assert sweeps == -(-N_DRAWS // CHUNK)
+    assert (s1["engine_level_steps"] - s0["engine_level_steps"]
+            >= sweeps * len(plan.levels))
