@@ -670,16 +670,19 @@ class CompiledWorkflow:
         # the compiled run keeps its ceiling arrays on device; re-derive them
         # host-side only if a curve query (Report.data_ceiling) asks.  The
         # thunk captures just the packed inputs, not the pack (whose device
-        # cache would otherwise stay pinned for the Report's lifetime).
+        # cache would otherwise stay pinned for the Report's lifetime), and
+        # just the upstream results, not ``results``: a cycle through it
+        # would keep the device-resident progress alive until the cyclic GC.
         proc_args, B_bat = pack.proc_args, pack.B_batched
         for name in self.order:
+            ups = {src: results[src] for (src, _o, _d) in self.edges_in[name]}
             results[name].ceilings = LazyCeilings(
-                lambda name=name: self._derive_ceilings(
-                    proc_args, B_bat, results, name))
+                lambda name=name, ups=ups, t0=results[name].t_start:
+                self._derive_ceilings(proc_args, B_bat, ups, t0, name))
         return results
 
-    def _derive_ceilings(self, proc_args: dict, B: int, results,
-                         name: str) -> list[BPL]:
+    def _derive_ceilings(self, proc_args: dict, B: int, upstream: dict,
+                         t_start: np.ndarray, name: str) -> list[BPL]:
         """Numpy twin of the in-trace ceiling construction (lazy path)."""
         wf = self.workflow
         proc = wf.processes[name]
@@ -691,7 +694,7 @@ class CompiledWorkflow:
         for dep in proc.data:
             if dep in edge_fns:
                 inner = compose_scalar(edge_fns[dep],
-                                       results[edge_src[dep]].progress)
+                                       upstream[edge_src[dep]].progress)
                 ceils.append(compose_scalar(proc.data[dep].requirement, inner))
             elif dep in args["ceil"]:
                 ceils.append(args["ceil"][dep].broadcast(B))
@@ -701,7 +704,7 @@ class CompiledWorkflow:
         if not ceils:
             p_end = float(proc.total_progress)
             ceils = [BPL.constant(np.full(B, p_end),
-                                  results[name].t_start.astype(np.float64))]
+                                  t_start.astype(np.float64))]
         return ceils
 
     # ------------------------------------------------------------------

@@ -218,6 +218,11 @@ class ServiceStats:
     #: lockstep steps of the fused engine: per sweep, the sum over topology
     #: levels of that level's loop trip count
     engine_level_steps: int = 0
+    #: bytes the fused engine brought back from the device in sweeps
+    engine_fetch_bytes: int = 0
+    #: solves whose progress something read (the engines' count, mirrored
+    #: by :meth:`AnalysisService.snapshot`)
+    engine_progress_fetches: int = 0
     latencies_s: deque = field(default_factory=lambda: deque(maxlen=4096))
 
     def latency_quantiles(self, qs: Sequence[float] = (0.5, 0.99)
@@ -280,6 +285,8 @@ class ServiceStats:
             "mc_draws_direct": self.mc_draws_direct,
             "mc_draws_materialized": self.mc_draws_materialized,
             "engine_level_steps": self.engine_level_steps,
+            "engine_fetch_bytes": self.engine_fetch_bytes,
+            "engine_progress_fetches": self.engine_progress_fetches,
         }
 
 
@@ -902,8 +909,10 @@ class AnalysisService:
         executables from artifacts) vs ``cold_traces`` (XLA traces this
         process actually paid, including artifact exports)."""
         with self._lock:
-            snap = self.stats.snapshot()
             engines = list(self._engines.values())
+            self.stats.engine_progress_fetches = sum(
+                getattr(e, "progress_fetches", 0) for e in engines)
+            snap = self.stats.snapshot()
         snap["warm_hits"] = sum(getattr(e, "aot_hits", 0) for e in engines)
         snap["cold_traces"] = sum(getattr(e, "trace_count", 0)
                                   for e in engines)
@@ -1014,22 +1023,24 @@ class AnalysisService:
         """One guarded fused sweep + fault hooks + the degradation guard."""
         if self._faults is not None:
             self._faults.before_sweep()
-        steps0 = self._level_steps(plan)
+        steps0, bytes0 = self._engine_counts(plan)
         rep = plan.sweep(pack, backend=self.backend)
-        steps = self._level_steps(plan) - steps0
+        steps, nbytes = self._engine_counts(plan)
         with self._lock:
             self.stats.sweeps += 1
-            self.stats.engine_level_steps += steps
+            self.stats.engine_level_steps += steps - steps0
+            self.stats.engine_fetch_bytes += nbytes - bytes0
         if self._faults is not None:
             rep = self._faults.after_sweep(rep)
         return self._degrade_guard(plan, pack, rep, B_real)
 
     @staticmethod
-    def _level_steps(plan: CompiledWorkflow) -> int:
-        """Lockstep steps the plan's fused engine has run (one worker sweeps,
-        so the delta around a sweep is that sweep's)."""
+    def _engine_counts(plan: CompiledWorkflow) -> tuple[int, int]:
+        """Lockstep steps the plan's fused engine has run and bytes it has
+        fetched (one worker sweeps, so the delta around a sweep is that
+        sweep's)."""
         eng = plan._jax_engine
-        return eng.level_steps if eng is not None else 0
+        return (eng.level_steps, eng.fetch_bytes) if eng is not None else (0, 0)
 
     def _degrade_guard(self, plan: CompiledWorkflow, pack: ScenarioPack,
                        rep: Report, B_real: int) -> Report:

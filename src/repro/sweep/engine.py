@@ -48,7 +48,12 @@ MAX_LOCKSTEP_ITERS = 20_000
 
 @dataclass
 class BatchProcResult:
-    """Batched analogue of :class:`repro.core.solver.ProgressResult`."""
+    """Batched analogue of :class:`repro.core.solver.ProgressResult`.
+
+    ``progress`` may be given as a zero-argument callable instead of a
+    :class:`BPL`: the fused engine leaves progress on the device, and the
+    first read of ``progress`` calls it and keeps the BPL it returns.
+    """
 
     name: str
     p_end: float
@@ -75,6 +80,22 @@ class BatchProcResult:
         degradation guard and the chaos tests can attribute garbage rows
         without re-deriving them from the merged report."""
         return np.isnan(self.finish)
+
+
+def _get_progress(self) -> BPL:
+    p = self.__dict__["_progress"]
+    if not isinstance(p, BPL):
+        p = self.__dict__["_progress"] = p()
+    return p
+
+
+def _set_progress(self, p) -> None:
+    self.__dict__["_progress"] = p
+
+
+# after the decorator, so the dataclass keeps ``progress`` as a field and
+# its ``__init__`` assigns through the setter
+BatchProcResult.progress = property(_get_progress, _set_progress)
 
 
 def _res_tables(proc: Process):

@@ -51,6 +51,8 @@ to match the scalar solver's tolerances.
 
 from __future__ import annotations
 
+import functools
+import threading
 from dataclasses import dataclass
 
 import numpy as np
@@ -110,6 +112,35 @@ class LazyCeilings:
 
     def __len__(self):
         return len(self._get())
+
+
+class _ProgressFetch:
+    """The progress of every process of one solve, left on the device until
+    something reads one: the first read brings all of it back in one
+    ``jax.device_get`` and drops the device buffers.
+
+    ``dev`` maps a process name to its progress arrays as the compiled run
+    returned them; ``host`` un-shards and slices a fetched array as
+    :meth:`JaxSweepEngine._wrap` does.  Any thread may read (the service's
+    worker, or a caller's curve query).
+    """
+
+    def __init__(self, engine: "JaxSweepEngine", dev: dict, host):
+        self._engine, self._dev, self._host = engine, dev, host
+        self._val: dict | None = None
+        self._lock = threading.Lock()
+
+    def get(self, name: str) -> BPL:
+        with self._lock:
+            if self._val is None:
+                with TraceAnnotation("bm.engine.fetch_progress"):
+                    got = jax.device_get(self._dev)
+                self._engine._count_fetch(got, progress=True)
+                self._val = {n: BPL(*(self._host(a) for a in arrs))
+                             for n, arrs in got.items()}
+                self._engine = self._dev = self._host = None
+        return self._val[name]
+
 
 _INF = float("inf")
 
@@ -1060,6 +1091,12 @@ class JaxSweepEngine:
         #: lockstep steps run: per solve, the sum over topology levels of
         #: the level's loop trip count
         self.level_steps = 0
+        #: bytes of every host array :meth:`_wrap` and the lazy progress
+        #: fetch brought back from the device
+        self.fetch_bytes = 0
+        #: solves whose progress something read (one fetch per solve)
+        self.progress_fetches = 0
+        self._fetch_lock = threading.Lock()
         #: call-signature census per (B, shards, iter_cap, ramps): the input
         #: aval pytrees actually solved, recorded so :meth:`export_entries`
         #: AOT-serializes exactly the executables a warm start will need
@@ -1448,8 +1485,9 @@ class JaxSweepEngine:
             # recompile and every re-sweep after runs with tight buffers;
             # later deeper packs still double back up through the overflow
             # ladder (the key is set, so no second down-ratchet can thrash).
-            actual = max((int(np.asarray(out[ps.name]["iterations"]).max())
-                          for ps in self.spec.procs), default=1)
+            actual = max((int(np.asarray(out[ls.procs[0].name]
+                                         ["iterations"]).max())
+                          for ls in self.spec.levels), default=1)
             cap = min(cap, 1 << max(actual - 1, 0).bit_length())
         self._proven_caps[pkey] = cap
         with TraceAnnotation("bm.engine.fetch"):
@@ -1462,16 +1500,30 @@ class JaxSweepEngine:
     def _wrap(self, out, B: int, shards: int,
               scenario_ids: list[int] | None = None,
               ) -> dict[str, BatchProcResult]:
-        def host(x):
-            a = np.asarray(x)
+        """Finish times, shares and trip counts in one ``jax.device_get``;
+        progress stays on the device until something reads it
+        (:class:`_ProgressFetch`: the MC and what-if paths never do)."""
+        def host(a):
             if shards > 1:  # (D, Bp/D, ...) -> (Bp, ...) -> drop padding
                 a = a.reshape((-1,) + a.shape[2:])
             return a[:B]
 
+        procs, levels = self.spec.procs, self.spec.levels
+        # every process of a level reports the level's trip count
+        got = jax.device_get({
+            "finish": [out[ps.name]["finish"] for ps in procs],
+            "share": [out[ps.name]["share"] for ps in procs],
+            "iterations": [out[ls.procs[0].name]["iterations"]
+                           for ls in levels]})
+        self._count_fetch(got)
+        iterations = {ps.name: int(it.max())
+                      for ls, it in zip(levels, got["iterations"])
+                      for ps in ls.procs}
+        lazy = _ProgressFetch(self, {ps.name: out[ps.name]["progress"]
+                                     for ps in procs}, host)
         results: dict[str, BatchProcResult] = {}
-        for ps in self.spec.procs:
-            r = out[ps.name]
-            finish = host(r["finish"])
+        for ps, finish, share in zip(procs, got["finish"], got["share"]):
+            finish, share = host(finish), host(share)
             # gate-never-finishes: same error surface as the numpy engine;
             # t_start is re-derived from the gate finishes (not shipped back)
             t0 = np.zeros(B)
@@ -1484,20 +1536,25 @@ class JaxSweepEngine:
                     raise ValueError(f"gate {g!r} of {ps.name!r} never "
                                      f"finishes (scenario {bad})")
                 t0 = np.maximum(t0, gf)
-            progress = BPL(*(host(a) for a in r["progress"]))
             K, L = len(ps.data_names), len(ps.res_names)
-            share = host(r["share"])
             kinds = ["data"] * K + ["resource"] * L
             names = list(ps.data_names) + list(ps.res_names)
             if not K:
                 kinds, names = ["data"] + kinds, ["<none>"] + names
                 share = np.concatenate([np.zeros((B, 1)), share], 1)
             results[ps.name] = BatchProcResult(
-                name=ps.name, p_end=ps.p_end, t_start=t0,
-                finish=finish, progress=progress, ceilings=None,
+                name=ps.name, p_end=ps.p_end, t_start=t0, finish=finish,
+                progress=functools.partial(lazy.get, ps.name), ceilings=None,
                 factor_kinds=kinds, factor_names=names, share_seconds=share,
-                iterations=int(np.asarray(r["iterations"]).max()))
+                iterations=iterations[ps.name])
         return results
+
+    def _count_fetch(self, tree, progress: bool = False) -> None:
+        """Count a fetched tree's bytes (any thread may fetch progress)."""
+        nbytes = sum(a.nbytes for a in jax.tree_util.tree_leaves(tree))
+        with self._fetch_lock:
+            self.fetch_bytes += nbytes
+            self.progress_fetches += progress
 
     # -- AOT export / adopt (durable plan artifacts) ------------------------
     def _lookup_aot(self, B: int, shards: int, cap: int, ramps: bool, dev):

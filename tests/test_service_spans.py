@@ -8,11 +8,14 @@ are read back with ``ProfileData``.  Checked: the span names are exactly
 the documented set, they nest by layer, one ``bm.sweep`` per counted
 sweep, the spans of one Monte Carlo call carry its request id on both
 threads, their number grows with the chunks and not with the draws, and
-the profiler leaves every result bit-identical.
+the profiler leaves every result bit-identical.  The served paths read no
+progress; a curve query on a direct sweep's report, traced apart, fetches
+it once per solve.
 """
 
 from __future__ import annotations
 
+import contextlib
 import glob
 import re
 from pathlib import Path
@@ -34,7 +37,7 @@ WHATIF = [sweep_scenarios([x, x + 0.05]) for x in (0.2, 0.4, 0.6)]
 def documented_spans() -> set:
     """The span names of PERF.md's "Spans and counters" table."""
     text = (ROOT / "PERF.md").read_text()
-    return set(re.findall(r"^\| `(bm\.[a-z.]+)` \|", text, re.M))
+    return set(re.findall(r"^\| `(bm\.[a-z._]+)` \|", text, re.M))
 
 
 def documented_counters() -> set:
@@ -103,16 +106,44 @@ def served(plan, tmp_path_factory):
     return out
 
 
+@pytest.fixture(scope="module")
+def curve_spans(plan, tmp_path_factory):
+    """Spans of two direct sweeps and three curve queries on each report,
+    traced after an untraced warm-up."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    pack = plan.prepare([sc for scs in WHATIF for sc in scs])
+    ts = np.linspace(0.0, 300.0, 5)
+    for traced in (False, True):
+        reps = [plan.sweep(pack, backend="jax") for _ in range(2)]
+        d = str(tmp_path_factory.mktemp("trace_curve"))
+        with (jax.profiler.trace(d, profiler_options=opts) if traced
+              else contextlib.nullcontext()):
+            for rep in reps:
+                rep.sample_progress("task3", ts)
+                rep.data_ceiling("task3", ts)
+                rep.kernel_finish_times("task1")
+    return spans(d)
+
+
 def inside(child, parent) -> bool:
     return (child[3] == parent[3] and parent[1] <= child[1]
             and child[2] <= parent[2])
 
 
-def test_span_names_are_the_documented_set(served):
+def test_span_names_are_the_documented_set(served, curve_spans):
     doc = documented_spans()
-    assert len(doc) == 9, doc
+    assert len(doc) == 10, doc
     seen = {s[0] for kind in served for s in served[kind][4]}
-    assert seen == doc
+    assert seen == doc - {"bm.engine.fetch_progress"}
+    assert {s[0] for s in curve_spans} == {"bm.engine.fetch_progress"}
+
+
+def test_progress_fetched_once_per_solve_on_the_reading_thread(curve_spans):
+    """Two reports, three curve queries each: two fetches, on the thread
+    that asked, outside any sweep."""
+    assert len(curve_spans) == 2
+    assert len({s[3] for s in curve_spans}) == 1
 
 
 @pytest.mark.parametrize("child,parent", [
@@ -185,9 +216,13 @@ def test_profiler_leaves_results_bit_identical(served, kind):
 def test_documented_counters_are_served(served):
     doc = documented_counters()
     assert doc == {"queue_wait_s", "queue_waits", "mc_draws_direct",
-                   "mc_draws_materialized", "engine_level_steps"}
+                   "mc_draws_materialized", "engine_level_steps",
+                   "engine_fetch_bytes", "engine_progress_fetches"}
     for kind in served:
         assert doc <= set(served[kind][3])
+        s0, s1 = served[kind][2], served[kind][3]
+        assert s1["engine_fetch_bytes"] > s0["engine_fetch_bytes"]
+        assert s1["engine_progress_fetches"] == s0["engine_progress_fetches"]
 
 
 def test_level_steps_sum_each_sweeps_level_loops(plan, served):
