@@ -11,11 +11,9 @@ function, and bottleneck-gain estimates — all returning one `Report` type.
 """
 
 import time
-import warnings
 
 import numpy as np
 
-from repro import sweep
 from repro.analysis import scenarios
 from repro.configs.paper_workflow import build_workflow, sweep_scenarios
 
@@ -52,21 +50,7 @@ g = scenarios.grid({"task1.cpu": [1.0, 2.0, 4.0], "dl1.link": [0.5, 1.0, 2.0]})
 rg = plan.sweep(g)
 print(f"\nswept a {len(g)}-cell grid; best: {rg.top_k(1)[0]}")
 
-scs = sweep_scenarios(np.linspace(0.02, 0.98, 600))
-plan.sweep(scs)  # warm
-reps = 5
-t0 = time.perf_counter()
-for _ in range(reps):
-    res = plan.sweep(scs)
-dt_plan = (time.perf_counter() - t0) / reps
-t0 = time.perf_counter()
-with warnings.catch_warnings():          # the shim warns: it is deprecated
-    warnings.simplefilter("ignore", DeprecationWarning)
-    for _ in range(reps):
-        sweep.analyze(base, scs)  # the legacy shim: re-compiles every call
-dt_shim = (time.perf_counter() - t0) / reps
-print(f"resweep of 600 scenarios: compiled plan {dt_plan * 1e3:.1f} ms vs "
-      f"legacy analyze {dt_shim * 1e3:.1f} ms "
-      f"({dt_shim / dt_plan:.2f}x, same results)")
-print(f"winner: {res.top_k(1)[0][1]} at {res.top_k(1)[0][2]:.1f} s; "
-      f"all scenarios on the {res.backend!r} backend")
+res = plan.sweep(sweep_scenarios(np.linspace(0.02, 0.98, 600)))
+print(f"swept 600 link prioritizations; winner: {res.top_k(1)[0][1]} at "
+      f"{res.top_k(1)[0][2]:.1f} s; all scenarios on the {res.backend!r} "
+      "backend")

@@ -451,7 +451,7 @@ class CompiledWorkflow:
                             deadline_s=deadline_s)
 
     def sweep(self, scenario_list: "Sequence[Scenario | ScenarioSpec] | ScenarioPack",
-              *args, backend: str = "auto") -> Report:
+              *, backend: str = "auto") -> Report:
         """Analyze B what-if scenarios in one batched pass.
 
         ``scenario_list`` is either a list of scenarios/specs or a
@@ -475,21 +475,8 @@ class CompiledWorkflow:
           summary warning.  Per-scenario routing is recorded in
           ``Report.backends``.
 
-        ``backend`` is keyword-only (unified across the analysis surface);
-        the old positional form is accepted for one release with a
-        :class:`DeprecationWarning`.
+        ``backend`` is keyword-only (unified across the analysis surface).
         """
-        if args:
-            if len(args) > 1:
-                raise TypeError(
-                    f"sweep() takes one scenario list and keyword arguments "
-                    f"({len(args) + 1} positional arguments given)")
-            warnings.warn(
-                "plan.sweep(scenarios, backend) with a positional backend is "
-                "deprecated; pass backend as a keyword: "
-                "plan.sweep(scenarios, backend=...)",
-                DeprecationWarning, stacklevel=2)
-            backend = args[0]
         if backend not in SWEEP_BACKENDS:
             raise ValueError(f"unknown backend {backend!r} "
                              f"(expected {'|'.join(SWEEP_BACKENDS)})")

@@ -15,8 +15,6 @@ Batched reports additionally expose the Pallas-backed curve queries
 :meth:`Report.kernel_finish_times`) and record the backend every scenario
 actually ran on (``backends`` — ``"batched"`` fast path vs ``"loop"``
 scalar fallback).
-
-``repro.sweep.SweepResult`` is a back-compat alias of this class.
 """
 
 from __future__ import annotations
@@ -53,9 +51,9 @@ class BottleneckRow:
 class FinishTimes(dict[str, np.ndarray]):
     """Per-process finish times: a mapping AND the unified accessor.
 
-    ``report.finish["dl1"]`` returns the raw ``(B,)`` array (back-compat
-    with the original ``SweepResult.finish`` dict); ``report.finish("dl1")``
-    returns a float for scalar reports and the array for sweeps.
+    ``report.finish["dl1"]`` returns the raw ``(B,)`` array;
+    ``report.finish("dl1")`` returns a float for scalar reports and the
+    array for sweeps.
     """
 
     scalar: bool = False

@@ -2,8 +2,7 @@
 
 Replaces hand-rolled ``sweep.Scenario`` dict construction with three
 builders that all produce :class:`ScenarioSpec` objects (accepted anywhere a
-``Scenario`` is — ``CompiledWorkflow.sweep``, ``sweep.analyze``,
-``ScenarioBatch``):
+``Scenario`` is — ``CompiledWorkflow.sweep``, ``ScenarioBatch``):
 
 * :func:`override` — one scenario from explicit replacement functions,
 * :func:`scale_resource` — one scenario per factor, scaling a *base*

@@ -225,7 +225,7 @@ def _normalize_spec(spec: Any) -> list[ScenarioSpec]:
     return specs
 
 
-def sample_spec(plan: Any, spec: Any, n: int, *args,
+def sample_spec(plan: Any, spec: Any, n: int, *,
                 seed: int = 0) -> MCSamples:
     """Sample ``n`` concrete scenarios from a distribution-valued spec.
 
@@ -243,16 +243,6 @@ def sample_spec(plan: Any, spec: Any, n: int, *args,
     """
     import jax
 
-    if args:  # seed is keyword-only now (unified across the analysis surface)
-        if len(args) > 1:
-            raise TypeError(
-                f"sample_spec() takes (plan, spec, n) and keyword arguments "
-                f"({len(args) + 3} positional arguments given)")
-        warnings.warn(
-            "sample_spec(plan, spec, n, seed) with a positional seed is "
-            "deprecated; pass seed as a keyword: sample_spec(..., seed=...)",
-            DeprecationWarning, stacklevel=2)
-        seed = args[0]
     if n < 1:
         raise ValueError(f"mc: need n >= 1 draws, got {n}")
     specs = _normalize_spec(spec)

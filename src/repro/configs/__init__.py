@@ -3,8 +3,8 @@
 ``--arch <id>`` resolution for every launcher goes through
 :func:`get_config` / :func:`get_smoke_config`.  The shape table mirrors the
 assignment: every architecture pairs with the four LM shapes; ``long_500k``
-only applies to sub-quadratic architectures (see DESIGN.md §4 for the skip
-rationale per arch).
+only applies to sub-quadratic architectures (:func:`is_subquadratic` decides
+which).
 """
 
 from __future__ import annotations

@@ -1,8 +1,8 @@
 """Persistent XLA compilation cache for the repo's entry points.
 
 ``use_compile_cache()`` is called by the programs a user runs
-(``chip_smoke.py``, ``python -m repro.launch.analyze``,
-``benchmarks/run.py``), never at library import: tests and library users
+(``chip_smoke.py``, ``perfbench/run.py``, ``python -m
+repro.launch.analyze``), never at library import: tests and library users
 keep JAX's default.
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; nothing is set

@@ -2,22 +2,17 @@
 
 BottleMod's selling point (Sect. 6/8) is that re-analysis is cheap enough to
 *try candidate allocations* online; Fig. 7 sweeps 600 link prioritizations.
-This package holds the lockstep engine (:mod:`.engine`), the batched
-piecewise-linear algebra (:mod:`.plin`) and the scenario packing
-(:mod:`.batch`) that power those sweeps.
-
-The public front door moved to :mod:`repro.analysis` (compile-once /
-query-many)::
+This package holds the lockstep engines (:mod:`.engine`, :mod:`.jax_engine`),
+the batched piecewise-linear algebra (:mod:`.plin`) and the scenario packing
+(:mod:`.batch`) that power those sweeps.  It imports nothing from
+:mod:`repro.analysis`; the front door is the compiled plan there
+(compile-once / query-many)::
 
     plan = workflow.compile()        # topo, validation, packing: ONCE
     res = plan.sweep(scenarios)      # ...then sweep as often as you like
     res.top_k(5); res.bottleneck_report(res.best())
 
-:func:`analyze` below is kept as a back-compat shim over that API; it
-re-compiles the workflow on every call, which is exactly the overhead the
-compiled plan avoids.
-
-Backends (``plan.sweep(..., backend=...)`` / ``analyze(..., backend=...)``):
+Backends (``plan.sweep(..., backend=...)``):
 
 * ``"jax"`` — the fused engine of :mod:`.jax_engine`: the same lockstep
   event loop as ``lax.while_loop`` over stacked state, the whole workflow
@@ -26,12 +21,12 @@ Backends (``plan.sweep(..., backend=...)`` / ``analyze(..., backend=...)``):
   single compiled dispatch.
 * ``"numpy"`` (alias ``"batched"``) — the lockstep engine of :mod:`.engine`:
   all scenarios advance one Algorithm-2 event per vectorized numpy
-  iteration; the reference backend the jax engine must agree with.  Curve
-  queries run on the Pallas ``ppoly_eval`` / ``ppoly_min_eval`` /
-  ``ppoly_first_crossing`` kernels.  Both batched engines serve the
-  piecewise-QUADRATIC class: data inputs of degree <= 2 and non-negative
-  piecewise-LINEAR resource rates (ramps — linear rate x linear requirement
-  gives quadratic progress pieces, solved in closed form).
+  iteration; the reference backend the jax engine must agree with, and the
+  path that needs no compile for one-off lists.  Both batched engines serve
+  the piecewise-QUADRATIC class: data inputs of degree <= 2 and
+  non-negative piecewise-LINEAR resource rates (ramps — linear rate x
+  linear requirement gives quadratic progress pieces, solved in closed
+  form).
 * ``"loop"`` — the scalar :func:`repro.core.solver.solve` per scenario; the
   reference the batched engines must agree with to float tolerance.
 * ``"auto"`` (default) — the fast path (jax for packs, numpy for lists) for
@@ -42,43 +37,11 @@ Backends (``plan.sweep(..., backend=...)`` / ``analyze(..., backend=...)``):
 
 from __future__ import annotations
 
-from repro.core.workflow import Workflow
-
 from .batch import Scenario, ScenarioBatch
 from .engine import BatchProcResult, solve_batch
 from .plin import BPL, UnsupportedScenario, compose_scalar
-from .result import BottleneckRow, Report, SweepResult
 
 __all__ = [
-    "Scenario", "ScenarioBatch", "SweepResult", "Report", "BottleneckRow",
-    "BatchProcResult", "BPL", "UnsupportedScenario", "analyze", "solve_batch",
-    "compose_scalar",
+    "Scenario", "ScenarioBatch", "BatchProcResult", "BPL",
+    "UnsupportedScenario", "solve_batch", "compose_scalar",
 ]
-
-
-def analyze(workflow: Workflow, scenarios: list[Scenario],
-            backend: str = "auto") -> Report:
-    """Analyze B what-if scenarios of ``workflow`` in one batched pass.
-
-    .. deprecated::
-        This is a back-compat shim that compiles the workflow on EVERY call
-        (validation, topo-sort, curve derivation, array packing).  Compile
-        once and sweep many instead::
-
-            plan = workflow.compile()
-            res = plan.sweep(scenarios, backend="auto")
-
-    Returns the unified :class:`repro.analysis.report.Report` with
-    per-scenario makespans, finish times, bottleneck shares, rankings,
-    and backend routing.
-    """
-    import warnings
-
-    from repro.analysis import compile_workflow
-
-    warnings.warn(
-        "repro.sweep.analyze(workflow, scenarios) is deprecated and "
-        "re-compiles the workflow on every call; migrate with "
-        "`plan = workflow.compile(); plan.sweep(scenarios, backend=...)`.",
-        DeprecationWarning, stacklevel=2)
-    return compile_workflow(workflow).sweep(scenarios, backend=backend)

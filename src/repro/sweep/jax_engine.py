@@ -1714,7 +1714,7 @@ def trace_report(plan, pack, *, iter_cap: int | None = None) -> dict:
     jaxpr equations inside the while bodies — the per-iteration dispatch
     cost), ``total_eqns`` (all equations at any depth) and ``hlo_lines``
     (unoptimized StableHLO op lines from ``jit(run).lower``).  Everything is
-    machine-independent, so benchmarks can gate on it like a timing.
+    machine-independent, so tests can pin it.
     """
     eng = getattr(plan, "_jax_engine", None) or JaxSweepEngine(plan)
     B = pack.B_batched

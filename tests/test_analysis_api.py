@@ -3,8 +3,7 @@
 Contracts under test:
 
 * ``CompiledWorkflow.solve()`` == ``Workflow.analyze()`` exactly,
-* ``CompiledWorkflow.sweep()`` == legacy ``sweep.analyze`` (which is now a
-  shim over it) == the scalar loop, to float tolerance,
+* ``CompiledWorkflow.sweep()`` == the scalar loop, to float tolerance,
 * repeated sweeps on one plan are deterministic (the plan caches are pure),
 * the unified ``Report`` accessors behave the same across scalar/batched,
 * ``whatif``/``gain``/``gains`` agree with the legacy ``core.bottleneck``
@@ -51,7 +50,7 @@ def test_scalar_report_accessors(plan):
     rep = plan.solve()
     assert isinstance(rep.makespan, float)
     assert isinstance(rep.finish("task3"), float)
-    # mapping access stays available (back-compat with SweepResult.finish)
+    # mapping access stays available next to the call form
     assert rep.finish["task3"].shape == (1,)
     (idx, label, ms), = rep.top_k(1)
     assert (idx, label) == (0, "base") and ms == rep.makespan
@@ -63,10 +62,7 @@ def test_scalar_report_accessors(plan):
 def test_sweep_matches_legacy_and_loop(plan):
     scs = sweep_scenarios(np.linspace(0.1, 0.9, 9))
     rb = plan.sweep(scs, backend="batched")
-    with pytest.deprecated_call():
-        shim = sweep.analyze(build_workflow(0.5), scs, backend="batched")
     rl = plan.sweep(scs, backend="loop")
-    np.testing.assert_allclose(rb.makespan, shim.makespan, rtol=0, atol=0)
     np.testing.assert_allclose(rb.makespan, rl.makespan, rtol=1e-9)
     assert rb.backends == ["batched"] * 9
     assert rl.backends == ["loop"] * 9 and rl.backend == "loop"

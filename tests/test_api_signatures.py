@@ -5,9 +5,12 @@ The redesign PR unified ``seed`` / ``deadline_s`` / ``backend`` /
 ``max_batch`` across ``plan.*``, the service ``submit*``/``query*`` family
 and the samplers; this test pins that contract so a future method can't
 drift (e.g. re-introduce a positional ``backend`` or rename ``seed`` to
-``rng``)."""
+``rng``).  It also pins the layering: ``repro.core`` and ``repro.sweep``
+import nothing from ``repro.analysis``."""
 
+import ast
 import inspect
+import pathlib
 
 from repro.analysis.optimize import run_optimize
 from repro.analysis.plan import CompiledWorkflow
@@ -45,8 +48,7 @@ def test_unified_kwargs_present_and_keyword_only():
 
 def test_no_unified_kwarg_is_ever_positional():
     """Even where a unified knob is optional, it must never be acceptable
-    positionally — old positional forms go through the ``*args`` shim with a
-    DeprecationWarning, not through the signature."""
+    positionally."""
     for fn in SURFACE:
         for name, p in inspect.signature(fn).parameters.items():
             if name in UNIFIED:
@@ -64,3 +66,57 @@ def test_unified_defaults_agree():
                 defaults.setdefault(name, set()).add(p.default)
     assert defaults["deadline_s"] == {None}
     assert defaults["backend"] == {"auto"}
+
+
+# ------------------------------------------------------------ layering ----
+SRC = pathlib.Path(__file__).resolve().parents[1] / "src" / "repro"
+
+#: the one deliberate upward call: ``Workflow.compile()`` is the documented
+#: front door and builds the analysis layer's plan when it is called
+UPWARD_ENTRIES = {("core/workflow.py", "Workflow.compile")}
+
+
+def _imported_modules(node: ast.Import | ast.ImportFrom,
+                      package: list[str]) -> list[str]:
+    if isinstance(node, ast.Import):
+        return [a.name for a in node.names]
+    base = ".".join(package[:len(package) - node.level + 1]
+                    if node.level else [])
+    mod = ".".join(p for p in (base, node.module) if p)
+    return [mod] + [f"{mod}.{a.name}" for a in node.names]
+
+
+def _analysis_imports(path: pathlib.Path) -> list[str]:
+    rel = path.relative_to(SRC).as_posix()
+    package = ["repro", *rel.split("/")[:-1]]
+    hits: list[str] = []
+
+    def walk(nodes: list[ast.AST], scope: list[str]) -> None:
+        for node in nodes:
+            if (isinstance(node, ast.If)
+                    and ast.unparse(node.test).endswith("TYPE_CHECKING")):
+                walk(node.orelse, scope)  # the guarded body never runs
+                continue
+            if isinstance(node, (ast.Import, ast.ImportFrom)):
+                for mod in _imported_modules(node, package):
+                    upward = (mod == "repro.analysis"
+                              or mod.startswith("repro.analysis."))
+                    if upward and (rel, ".".join(scope)) not in UPWARD_ENTRIES:
+                        hits.append(f"{rel}:{node.lineno} imports {mod}")
+            if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef,
+                                 ast.ClassDef)):
+                walk(node.body, scope + [node.name])
+            else:
+                walk(list(ast.iter_child_nodes(node)), scope)
+
+    walk(ast.parse(path.read_text()).body, [])
+    return hits
+
+
+def test_lower_layers_do_not_import_analysis():
+    """``repro.core`` and ``repro.sweep`` sit below ``repro.analysis``:
+    importing them must not load the analysis layer."""
+    hits = [hit for layer in ("core", "sweep")
+            for path in sorted((SRC / layer).rglob("*.py"))
+            for hit in _analysis_imports(path)]
+    assert hits == []

@@ -178,6 +178,7 @@ def test_degraded_rows_survive_coalescing(plan, ref):
         finally:
             svc.close()
     assert snap["sweeps"] == 1, snap  # still ONE fused sweep
+    assert snap["degraded"] == 1, snap  # row 5 lies past the 4-row batch
     for i, rep in enumerate(reps):
         assert rep.B == 1
         np.testing.assert_allclose(rep.makespans, ref.makespans[i:i + 1],

@@ -11,7 +11,6 @@ these numbers is a behavior change and must update this file deliberately.
 import numpy as np
 import pytest
 
-from repro import sweep
 from repro.configs.paper_workflow import build_workflow, sweep_scenarios
 from repro.core import bottleneck_report
 
@@ -88,9 +87,8 @@ def test_golden_fig7_improvement():
 
 
 def test_golden_compiled_api_reproduces_pinned_numbers():
-    """The compile-once front door hits the same pinned numbers as the
-    legacy paths — ``solve()`` vs ``Workflow.analyze()`` and ``sweep()`` vs
-    ``sweep.analyze`` (acceptance criterion of the Analysis API redesign)."""
+    """The compile-once front door hits the same pinned numbers as
+    ``Workflow.analyze()``, through ``solve()`` and ``sweep()``."""
     plan = build_workflow(0.5).compile()
     rep = plan.solve()
     assert rep.makespan == pytest.approx(GOLDEN_MAKESPAN[0.50], rel=REL)
@@ -101,11 +99,6 @@ def test_golden_compiled_api_reproduces_pinned_numbers():
         assert shares[key] == pytest.approx(expect, rel=1e-6), key
 
     swept = plan.sweep(sweep_scenarios([0.50, 0.95]), backend="batched")
-    with pytest.deprecated_call():
-        legacy = sweep.analyze(build_workflow(0.5),
-                               sweep_scenarios([0.50, 0.95]),
-                               backend="batched")
-    np.testing.assert_array_equal(swept.makespan, legacy.makespan)
     for i, frac in enumerate((0.50, 0.95)):
         assert swept.makespan[i] == pytest.approx(GOLDEN_MAKESPAN[frac], rel=REL)
         for name, expect in GOLDEN_FINISH[frac].items():

@@ -105,6 +105,9 @@ def test_paper_workflow_traces_to_three_while_loops():
     rep = trace_report(plan, pack)
     assert rep["while_loops"] == 3
     assert rep["while_loops"] == len(plan.levels)
+    # the per-iteration op budget: 20 % over the 1247 loop-body equations
+    # of the first level-fused engine; the count does not depend on B
+    assert rep["body_eqns"] <= 1496, rep
 
 
 def test_diamond_traces_to_one_loop_per_level():

@@ -221,21 +221,22 @@ def test_optimize_report_summary_fields(plan):
     assert "task1.cpu" in s and "evals" in s and "baseline" in s
 
 
-# ------------------------------------------------- deprecation migrations ----
-def test_positional_backend_in_sweep_warns(plan):
-    scs = sweep_scenarios([0.5])
-    with pytest.deprecated_call():
-        rep = plan.sweep(scs, "batched")
-    np.testing.assert_array_equal(
-        rep.makespan, plan.sweep(scs, backend="batched").makespan)
+# ------------------------------------------------ keyword-only knobs ----
+def _sweep_positional_backend(plan):
+    plan.sweep(sweep_scenarios([0.5]), "jax")
 
 
-def test_positional_seed_in_sample_spec_warns(plan):
+def _sample_spec_positional_seed(plan):
     from repro.analysis.uncertainty import sample_spec
-    with pytest.deprecated_call():
-        a = sample_spec(plan, mc_spec(), 4, 7)
-    b = sample_spec(plan, mc_spec(), 4, seed=7)
-    assert [s.label for s in a.scenarios] == [s.label for s in b.scenarios]
+    sample_spec(plan, mc_spec(), 4, 7)
+
+
+@pytest.mark.parametrize("call", [_sweep_positional_backend,
+                                  _sample_spec_positional_seed],
+                         ids=["sweep-backend", "sample_spec-seed"])
+def test_positional_unified_kwarg_raises(plan, call):
+    with pytest.raises(TypeError, match="positional argument"):
+        call(plan)
 
 
 def test_front_door_exports():

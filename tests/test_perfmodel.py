@@ -1,6 +1,9 @@
 """perfmodel tests: HLO analyzer (trip counts, collectives), roofline,
 BottleMod step model, sharding rules."""
 
+import json
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,7 +13,8 @@ from repro.configs import SHAPES
 from repro.distributed.sharding import AxisRules, DEFAULT_RULES, axis_rules, constrain
 from repro.perfmodel.hlo import analyze_hlo
 from repro.perfmodel.roofline import roofline_terms
-from repro.perfmodel.stepmodel import StepModelInputs, build_step_workflow, predict
+from repro.perfmodel.stepmodel import (StepModelInputs, build_step_workflow,
+                                      from_dryrun_record, predict)
 
 
 # --------------------------------------------------------------- HLO parser --
@@ -109,6 +113,22 @@ def test_stepmodel_checkpoint_stall():
     res = p.workflow.analyze()
     # each checkpoint needs 100 s of writing but steps produce work every ~0.1 s
     assert res.finish("checkpoint") > res.finish("train_step")
+
+
+def test_stepmodel_from_the_dryrun_fixture():
+    """The committed dry-run record is one ok single-mesh cell; fed its
+    per-device numbers, the step model binds on the record's roofline term
+    at the roofline's step time."""
+    fixture = pathlib.Path(__file__).parent / "fixtures" / "dryrun_mini.json"
+    rec = json.loads(fixture.read_text())
+    assert (rec["status"], rec["mesh"], rec["tag"]) == ("ok", "single", "")
+    p = predict(from_dryrun_record(rec, n_steps=10,
+                                   data_rate_steps_per_s=1e6))
+    roof = rec["roofline"]
+    term = {"compute": "mxu_flops", "memory": "hbm_bytes",
+            "collective": "ici_bytes"}[roof["dominant"]]
+    assert p.dominant() == term
+    assert p.step_time_s == pytest.approx(roof["step_lower_bound_s"], rel=1e-3)
 
 
 # --------------------------------------------------------------- sharding ----

@@ -154,9 +154,9 @@ def test_randomized_ramp_sweeps_match_scalar(seed):
     rng = RNG(seed)
     wf = _random_workflow(rng)
     scs = _ramp_scenarios(rng, wf, 8)
-    rb = sweep.analyze(wf, scs, backend="numpy")
+    rb = wf.compile().sweep(scs, backend="numpy")
     assert set(rb.backends) == {"batched"}
-    _assert_match(rb, sweep.analyze(wf, scs, backend="loop"))
+    _assert_match(rb, wf.compile().sweep(scs, backend="loop"))
 
 
 @pytest.mark.parametrize("seed", [2, 7])
@@ -183,8 +183,8 @@ def test_hypothesis_property_quadratic_sweep_matches_scalar():
         rng = RNG(seed)
         wf = _random_workflow(rng)
         scs = _ramp_scenarios(rng, wf, 4)
-        _assert_match(sweep.analyze(wf, scs, backend="numpy"),
-                      sweep.analyze(wf, scs, backend="loop"))
+        _assert_match(wf.compile().sweep(scs, backend="numpy"),
+                      wf.compile().sweep(scs, backend="loop"))
 
     run()
 
@@ -197,9 +197,9 @@ def test_quadratic_data_input_batched():
     scs = [sweep.Scenario(label=f"r{r}",
                           resource_inputs={("dl", "link"): PPoly.constant(r)})
            for r in (0.3, 2.0, 800.0)]
-    rb = sweep.analyze(wf, scs, backend="numpy")
+    rb = wf.compile().sweep(scs, backend="numpy")
     assert set(rb.backends) == {"batched"}
-    _assert_match(rb, sweep.analyze(wf, scs, backend="loop"))
+    _assert_match(rb, wf.compile().sweep(scs, backend="loop"))
 
 
 def test_tangency_tiebreak_matches_euler():
@@ -214,8 +214,8 @@ def test_tangency_tiebreak_matches_euler():
         np.array([0.0]), [np.array([0.0, 40.0, -0.18])])
     ramp = PPoly.pwlinear([0.0, 80.0], [40.0, 0.0])
     scs = [sweep.Scenario(label="t", resource_inputs={("dl", "link"): ramp})]
-    rb = sweep.analyze(wf, scs, backend="numpy")
-    rl = sweep.analyze(wf, scs, backend="loop")
+    rb = wf.compile().sweep(scs, backend="numpy")
+    rl = wf.compile().sweep(scs, backend="loop")
     _assert_match(rb, rl)
     proc = wf.processes["dl"]
     ts, ps, fin = solve_euler(proc, {"file": wf.external_data["dl"]["file"]},

@@ -281,44 +281,3 @@ def test_summary_warning_fires_exactly_once_per_sweep():
                    if "fell back to the scalar loop" in str(w.message)]
         assert len(summary) == 1
         assert "1/3" in str(summary[0].message)
-
-
-def test_bench_compare_rows():
-    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
-    try:
-        from run import compare_rows
-    finally:
-        sys.path.pop(0)
-    old = [{"name": "a", "us_per_call": 100.0},
-           {"name": "b", "us_per_call": 100.0},
-           {"name": "c", "us_per_call": None, "skipped": "no data"},
-           {"name": "gone", "us_per_call": 5.0}]
-    new = [{"name": "a", "us_per_call": 10.0},     # 10x improvement
-           {"name": "b", "us_per_call": 130.0},    # >20% regression
-           {"name": "c", "us_per_call": 7.0},      # old side unusable
-           {"name": "fresh", "us_per_call": 3.0}]  # new row
-    lines, regressions = compare_rows(old, new)
-    assert regressions == ["b"]
-    text = "\n".join(lines)
-    assert "10.00x" in text and "REGRESSION" in text
-    assert "new row" in text and "skipped" in text
-    # within threshold: no regression
-    _, ok = compare_rows([{"name": "a", "us_per_call": 100.0}],
-                         [{"name": "a", "us_per_call": 115.0}])
-    assert ok == []
-
-
-def test_bench_compare_null_vs_null_row_is_informational():
-    """Regression: a row untimed on BOTH sides (roofline_cells' explicit
-    skip row) must be reported as informational and never gate (exit 0)."""
-    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
-    try:
-        from run import compare_rows
-    finally:
-        sys.path.pop(0)
-    skip = {"name": "roofline_cells", "us_per_call": None,
-            "derived": "skipped: no dryrun results"}
-    lines, regressions = compare_rows([skip], [dict(skip)])
-    assert regressions == []
-    assert "informational" in "\n".join(lines)
-    assert "no timing on one side" not in "\n".join(lines)

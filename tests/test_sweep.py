@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro import analysis, sweep
+from repro.analysis.report import Report
 from repro.configs.paper_workflow import build_workflow, sweep_scenarios
 from repro.core import DataDep, PPoly, Process, ResourceDep, Workflow
 
@@ -20,7 +21,7 @@ def _sweep(wf, scs, backend="auto"):
     return analysis.compile(wf).sweep(scs, backend=backend)
 
 
-def _assert_match(rb: sweep.Report, rl: sweep.Report):
+def _assert_match(rb: Report, rl: Report):
     np.testing.assert_allclose(rb.makespan, rl.makespan, rtol=RTOL, atol=1e-9)
     for pn in rb.order:
         fb, fl = rb.finish[pn], rl.finish[pn]
