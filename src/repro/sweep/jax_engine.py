@@ -20,7 +20,7 @@ resource slots never bind (infinite cap) and padded ceiling slots sit far
 above any real ceiling.
 
 The loop body is tuned for op count, not flops — XLA on CPU pays per-op
-dispatch: value/slope/next-breakpoint ceiling queries share one gathered
+dispatch: value/slope/next-breakpoint ceiling queries share one masked
 piece lookup (:func:`_locate`), the resource-cap and burst-antiderivative
 evaluations share the resource piece index, every record buffer write is ONE
 ``dynamic_update_slice`` per iteration (a stacked ``(nbuf, Lp, B, spi)``
@@ -170,18 +170,40 @@ def _valid(s):
     return s < PAD_START * 0.5
 
 
-def _piece_idx(s, t, tol):
-    """Piece index per query: ``s (..., P)``, ``t (...)`` -> ``(...)``."""
+# Lookups select by mask, never by element gather: on the TPU an XLA
+# ``gather`` fetches one element at a time (~12 ns per float32 element),
+# while a one-hot mask and a reduction over the indexed axis stream the
+# table at memory speed.  ``_pick`` is exact — one lane holds the value and
+# every other lane the dtype's least — so the selected bits (signed zeros,
+# infinities) are the gather's.
+
+def _onehot(i, n: int, axis: int = -1):
+    """Mask of index ``i`` along a new axis of length ``n`` (last or
+    first)."""
+    if axis == 0:
+        return i[None] == jnp.arange(n).reshape((n,) + (1,) * i.ndim)
+    return i[..., None] == jnp.arange(n)
+
+
+def _pick(a, hit, axis: int = -1):
+    """The element of ``a`` where the one-hot ``hit`` is set along
+    ``axis`` (``a`` broadcasts against ``hit``)."""
+    least = (jnp.iinfo(a.dtype).min if jnp.issubdtype(a.dtype, jnp.integer)
+             else -_INF)
+    return jnp.where(hit, a, jnp.asarray(least, a.dtype)).max(axis)
+
+
+def _piece_hit(s, t, tol):
+    """One-hot piece index per query: ``s (..., P)``, ``t (...)`` ->
+    ``(..., P)``; the piece is the last start ``<= t + tol`` (the first
+    when none is)."""
     cmp = s <= (t[..., None] + tol)
-    return jnp.maximum(cmp.sum(-1) - 1, 0)
-
-
-def _gather(a, i):
-    return jnp.take_along_axis(a, i[..., None], -1)[..., 0]
+    return _onehot(jnp.maximum(cmp.sum(-1) - 1, 0), s.shape[-1])
 
 
 def _locate(f, t):
-    """Piece index AND next breakpoint after ``t`` from ONE comparison.
+    """One-hot piece index AND next breakpoint after ``t`` from ONE
+    comparison.
 
     ``s > t + TIME_TOL`` is exactly the complement of the right-eval piece
     test ``s <= t + TIME_TOL``, so the two per-iteration queries the loop
@@ -191,18 +213,18 @@ def _locate(f, t):
     """
     s = f[0]
     cmp = s <= (t[..., None] + TIME_TOL)
-    i = jnp.maximum(cmp.sum(-1) - 1, 0)
+    hit = _onehot(jnp.maximum(cmp.sum(-1) - 1, 0), s.shape[-1])
     nb = jnp.where(_valid(s) & ~cmp, s, _INF).min(-1)
-    return i, nb
+    return hit, nb
 
 
 def _eval(f, t, tol):
     s, c0, c1 = f[:3]
-    i = _piece_idx(s, t, tol)
-    u = t - _gather(s, i)
+    h = _piece_hit(s, t, tol)
+    u = t - _pick(s, h)
     if len(f) == 4:
-        return _gather(c0, i) + (_gather(c1, i) + _gather(f[3], i) * u) * u
-    return _gather(c0, i) + _gather(c1, i) * u
+        return _pick(c0, h) + (_pick(c1, h) + _pick(f[3], h) * u) * u
+    return _pick(c0, h) + _pick(c1, h) * u
 
 
 def _eval_right(f, t):
@@ -216,26 +238,26 @@ def _eval_left(f, t):
 def _eval_slope_right(f, t):
     """(value, slope) at ``t`` sharing one piece-index computation."""
     s, c0, c1 = f[:3]
-    i = _piece_idx(s, t, TIME_TOL)
-    sl = _gather(c1, i)
-    u = t - _gather(s, i)
+    h = _piece_hit(s, t, TIME_TOL)
+    sl = _pick(c1, h)
+    u = t - _pick(s, h)
     if len(f) == 4:
-        q = _gather(f[3], i)
-        return _gather(c0, i) + (sl + q * u) * u, sl + 2.0 * q * u
-    return _gather(c0, i) + sl * u, sl
+        q = _pick(f[3], h)
+        return _pick(c0, h) + (sl + q * u) * u, sl + 2.0 * q * u
+    return _pick(c0, h) + sl * u, sl
 
 
 def _eval_slope_quad_right(f, t):
     """(value, slope, quad) at ``t`` — the quadratic widening of
     :func:`_eval_slope_right` (one shared piece lookup)."""
     s, c0, c1 = f[:3]
-    i = _piece_idx(s, t, TIME_TOL)
-    sl = _gather(c1, i)
-    u = t - _gather(s, i)
+    h = _piece_hit(s, t, TIME_TOL)
+    sl = _pick(c1, h)
+    u = t - _pick(s, h)
     if len(f) == 4:
-        q = _gather(f[3], i)
-        return _gather(c0, i) + (sl + q * u) * u, sl + 2.0 * q * u, q
-    return _gather(c0, i) + sl * u, sl, jnp.zeros_like(sl)
+        q = _pick(f[3], h)
+        return _pick(c0, h) + (sl + q * u) * u, sl + 2.0 * q * u, q
+    return _pick(c0, h) + sl * u, sl, jnp.zeros_like(sl)
 
 
 def _first_pos_root(a, b, c, tol=TIME_TOL):
@@ -355,7 +377,6 @@ def _compose(outer, inner, B):
         if quad:
             out = out + (jnp.where(pad, 0.0, a1 * inner[3]),)
         return out
-    o_s, o_c0, o_c1 = (jnp.asarray(a) for a in outer)
     for v in outer[0][1:]:  # static python loop over outer breakpoints
         cross = _first_at_or_above(inner, jnp.full(B, float(v)))
         cs = jnp.where(jnp.isfinite(cross), cross, PAD_START)
@@ -366,13 +387,20 @@ def _compose(outer, inner, B):
             cv, csl = _eval_slope_right(inner, cs)
             planes = _insert_col(planes, (cs, cv, csl))
     S, V, SL = planes[:3]
-    oi = jnp.maximum(jnp.searchsorted(o_s, V + TIME_TOL, side="right") - 1, 0)
-    c0 = o_c0[oi] + o_c1[oi] * (V - o_s[oi])
-    c1 = o_c1[oi] * SL
+    # the outer piece under each value (the last start <= V + TIME_TOL, the
+    # first when none is): a static loop of selects over the outer pieces
+    x = V + TIME_TOL
+    os_, oc0, oc1 = (jnp.full(V.shape, float(a[0])) for a in outer)
+    for j in range(1, len(outer[0])):
+        on = float(outer[0][j]) <= x
+        os_, oc0, oc1 = (jnp.where(on, float(a[j]), o)
+                         for a, o in zip(outer, (os_, oc0, oc1)))
+    c0 = oc0 + oc1 * (V - os_)
+    c1 = oc1 * SL
     pad = S >= PAD_START * 0.5
     out = (S, jnp.where(pad, 0.0, c0), jnp.where(pad, 0.0, c1))
     if quad:
-        out = out + (jnp.where(pad, 0.0, o_c1[oi] * planes[3]),)
+        out = out + (jnp.where(pad, 0.0, oc1 * planes[3]),)
     return out
 
 
@@ -552,8 +580,8 @@ def _solve_level(ls: _LevelSpec, C, IR, t0, B: int, iter_cap: int,
         As = _antiderivative(IR, linear_rate=ramps) if has_jumps else None
         A_plen = _piece_len(As) if has_jumps else None  # hoisted, invariant
         rbs = jnp.asarray(ls.rbs)                       # (Lr, Lp, 1, n_rb)
-        rc1s = jnp.broadcast_to(jnp.asarray(ls.rc1s), (Lr, Lp, B, n_rb))
-        jumpss = jnp.broadcast_to(jnp.asarray(ls.jumpss), (Lr, Lp, B, n_rb))
+        rc1s = jnp.asarray(ls.rc1s)
+        jumpss = jnp.asarray(ls.jumpss)
 
     def cond(st):
         return (st["it"] < iter_cap) & jnp.any(st["active"]
@@ -569,15 +597,15 @@ def _solve_level(ls: _LevelSpec, C, IR, t0, B: int, iter_cap: int,
 
         # ---- ceilings at t: value/slope/next-break from ONE piece lookup ---
         tC = jnp.broadcast_to(t, (nC, Lp, B))
-        iC, nbC = _locate(C, tC)
-        uC = tC - _gather(C[0], iC)
-        slC = _gather(C[2], iC)
+        hC, nbC = _locate(C, tC)
+        uC = tC - _pick(C[0], hC)
+        slC = _pick(C[2], hC)
         if ramps:
-            Q = _gather(C[3], iC)
-            V = _gather(C[1], iC) + (slC + Q * uC) * uC             # (nC,Lp,B)
+            Q = _pick(C[3], hC)
+            V = _pick(C[1], hC) + (slC + Q * uC) * uC               # (nC,Lp,B)
             S = slC + 2.0 * Q * uC
         else:
-            V = _gather(C[1], iC) + slC * uC                        # (nC,Lp,B)
+            V = _pick(C[1], hC) + slC * uC                          # (nC,Lp,B)
             S = slC
         if nC > 1:
             # value ties break on slope, then curvature: the ceiling that is
@@ -591,10 +619,11 @@ def _solve_level(ls: _LevelSpec, C, IR, t0, B: int, iter_cap: int,
                     1.0, jnp.abs(Smin)))
                 St = jnp.where(tie, Q, _INF)
             kstar = jnp.argmin(St, 0).astype(jnp.int32)
-            pd = jnp.take_along_axis(V, kstar[None], 0)[0]
-            pdslope = jnp.take_along_axis(S, kstar[None], 0)[0]
+            hk = _onehot(kstar, nC, axis=0)
+            pd = _pick(V, hk, 0)
+            pdslope = _pick(S, hk, 0)
             if ramps:
-                pdq = jnp.take_along_axis(Q, kstar[None], 0)[0]
+                pdq = _pick(Q, hk, 0)
         else:
             kstar = jnp.zeros((Lp, B), jnp.int32)
             pd, pdslope = V[0], S[0]
@@ -608,14 +637,14 @@ def _solve_level(ls: _LevelSpec, C, IR, t0, B: int, iter_cap: int,
         # piece starts, so one _locate serves r_now, tb_ir AND A(t)
         if Lr:
             tL = jnp.broadcast_to(t, (Lr, Lp, B))
-            iL, nbL = _locate(IR, tL)
-            uL = tL - _gather(IR[0], iL)
-            r_sl = _gather(IR[2], iL)
-            r_now = _gather(IR[1], iL) + r_sl * uL
+            hL, nbL = _locate(IR, tL)
+            uL = tL - _pick(IR[0], hL)
+            r_sl = _pick(IR[2], hL)
+            r_now = _pick(IR[1], hL) + r_sl * uL
             tb_ir = nbL.min(0)
             ri = jnp.maximum((rbs <= (p + ptol)[None, :, :, None]).sum(-1) - 1,
                              0)                                     # (Lr,Lp,B)
-            cl = _gather(rc1s, ri)
+            cl = _pick(rc1s, _onehot(ri, n_rb))
             caps = jnp.where(cl > 0, r_now / jnp.where(cl > 0, cl, 1.0), _INF)
             if ramps:
                 caps1 = jnp.where(cl > 0, r_sl / jnp.where(cl > 0, cl, 1.0),
@@ -628,11 +657,8 @@ def _solve_level(ls: _LevelSpec, C, IR, t0, B: int, iter_cap: int,
                 cond_bp = (rbs >= pp - ptol[None, :, :, None]) \
                     & (rbs > pp + ptol[None, :, :, None])
             has = cond_bp.any(-1)
-            pbidx = jnp.argmax(cond_bp, -1)                         # (Lr,Lp,B)
-            pb = jnp.where(has,
-                           _gather(jnp.broadcast_to(rbs, (Lr, Lp, B, n_rb)),
-                                   pbidx),
-                           _INF)
+            hb = _onehot(jnp.argmax(cond_bp, -1), n_rb)      # (Lr,Lp,B,n_rb)
+            pb = jnp.where(has, _pick(rbs, hb), _INF)
             if Lr > 1 and ramps:
                 smin = caps.min(0)
                 # value ties break on the cap derivative (falling cap wins)
@@ -640,7 +666,7 @@ def _solve_level(ls: _LevelSpec, C, IR, t0, B: int, iter_cap: int,
                 ctie = caps <= smin + VAL_RTOL * jnp.maximum(1.0, jnp.abs(smin_s))
                 lstar = jnp.argmin(jnp.where(ctie, caps1, _INF), 0).astype(jnp.int32)
                 smin1 = jnp.where(jnp.isfinite(smin),
-                                  jnp.take_along_axis(caps1, lstar[None], 0)[0],
+                                  _pick(caps1, _onehot(lstar, Lr, axis=0), 0),
                                   0.0)
             elif Lr > 1:
                 smin = caps.min(0)
@@ -651,8 +677,7 @@ def _solve_level(ls: _LevelSpec, C, IR, t0, B: int, iter_cap: int,
                 if ramps:
                     smin1 = jnp.where(jnp.isfinite(smin), caps1[0], 0.0)
             if has_jumps:
-                pjump = jnp.where(
-                    has, _gather(jumpss, pbidx), 0.0)
+                pjump = jnp.where(has, _pick(jumpss, hb), 0.0)
         else:
             tb_ir = jnp.full((Lp, B), _INF)
             smin = jnp.full((Lp, B), _INF)
@@ -680,19 +705,17 @@ def _solve_level(ls: _LevelSpec, C, IR, t0, B: int, iter_cap: int,
         if has_jumps:
             pinned = act[None] & (pjump > 0) & (jnp.abs(pb - p[None])
                                                 <= ptol[None])
-            uA = tL - _gather(As[0], iL)        # same pieces as the rate
-            a_now = _gather(As[1], iL) + _gather(As[2], iL) * uA
+            uA = tL - _pick(As[0], hL)          # same pieces as the rate
+            a_now = _pick(As[1], hL) + _pick(As[2], hL) * uA
             if ramps:
-                a_now = a_now + _gather(As[3], iL) * uA * uA
+                a_now = a_now + _pick(As[3], hL) * uA * uA
             need = a_now + pjump
             te = _first_at_or_above(As, need, tL, plen=A_plen)
             te = jnp.where(pinned, te, -_INF)
             stall_end = te.max(0)
             # ties keep the first resource (argmax returns the first max)
             stall_attr = (nC + jnp.argmax(te, 0)).astype(jnp.int32)
-            absorbed = absorbed | (pinned[..., None]
-                                   & (jnp.arange(n_rb)[None, None, None]
-                                      == pbidx[..., None]))
+            absorbed = absorbed | (pinned[..., None] & hb)
             stalled = act & (stall_end > -_INF)
             rec0 = (jnp.where(stalled, t, 0.0), jnp.where(stalled, p, 0.0),
                     jnp.zeros((Lp, B)),
@@ -873,21 +896,33 @@ def _solve_level(ls: _LevelSpec, C, IR, t0, B: int, iter_cap: int,
             "iterations": st["it"], "overflow": overflow}
 
 
-def _suffix_min(a):
-    """Suffix cumulative minimum along the last axis via log-step shifted
-    minima.  ``lax.cummin`` lowers to ``reduce-window`` on XLA CPU — an
-    O(R²) window scan costing ~100us per call at these shapes — while this
-    unrolls to ceil(log2 R) elementwise ``minimum`` ops that fuse."""
-    R = a.shape[-1]
-    big = jnp.asarray(np.iinfo(np.int64).max if jnp.issubdtype(a.dtype, jnp.integer)
-                      else _INF, a.dtype)
+def _shift_left(a, k: int, fill):
+    """``a`` moved ``k`` slots toward the front along the last axis,
+    ``fill`` in the vacated tail."""
+    return jnp.concatenate(
+        [a[..., k:], jnp.full(a.shape[:-1] + (k,), fill, a.dtype)], -1)
+
+
+def _suffix_argmin_take(idx, planes):
+    """Per slot, the payload of the slot at or after it with the least
+    ``idx``, for each ``(payload, fill)`` of ``planes`` (``fill`` past the
+    end) — ``take_along_axis(payload, suffix_min(idx))`` without the
+    gather.  Log-step shifted selects: ``lax.cummin`` lowers to
+    ``reduce-window`` on XLA CPU, an O(R²) window scan, while this unrolls
+    to ceil(log2 R) elementwise steps that fuse.  ``idx`` holds distinct
+    values except a shared "none" value, whose slots all carry ``fill``."""
+    R = idx.shape[-1]
+    big = jnp.iinfo(idx.dtype).max
+    out = [p for p, _f in planes]
     k = 1
     while k < R:
-        shifted = jnp.concatenate(
-            [a[..., k:], jnp.full(a.shape[:-1] + (k,), big, a.dtype)], -1)
-        a = jnp.minimum(a, shifted)
+        sh = _shift_left(idx, k, big)
+        take = sh < idx
+        idx = jnp.where(take, sh, idx)
+        out = [jnp.where(take, _shift_left(p, k, f), p)
+               for p, (_p, f) in zip(out, planes)]
         k *= 2
-    return a
+    return out
 
 
 def _suffix_or(m):
@@ -917,7 +952,6 @@ def _assemble_progress(T, C0, C1, M, t0, finish, p_end, R: int, C2=None):
     anchor the domain at ``t0``.
     """
     lead = finish.shape
-    ax = len(lead)
     M = M & (T < finish[..., None] - TIME_TOL)
     has_fin = jnp.isfinite(finish)
     pe = jnp.broadcast_to(p_end, lead)
@@ -930,18 +964,15 @@ def _assemble_progress(T, C0, C1, M, t0, finish, p_end, R: int, C2=None):
     # cumulative-min over masked column indices (no sequential scan)
     P1 = R + 1
     idx = jnp.where(Mx, jnp.arange(P1), P1)
-    nxt = _suffix_min(idx)
-    grab = lambda a, fill: jnp.take_along_axis(  # noqa: E731
-        jnp.concatenate([a, jnp.full(lead + (1,), fill)], -1), nxt, -1)
-    Sf = grab(S, PAD_START)
-    C0f = grab(C0x, 0.0)
-    C1f = grab(C1x, 0.0)
-    empty = ~Mx.any(-1)
-    Sf = Sf.at[..., 0].set(jnp.where(empty, t0, Sf[..., 0]))
+    planes = [(S, PAD_START), (C0x, 0.0), (C1x, 0.0)]
     if C2 is not None:
-        C2f = grab(jnp.concatenate([C2, jnp.zeros(lead + (1,))], -1), 0.0)
-        return (Sf, C0f, C1f, C2f)
-    return (Sf, C0f, C1f)
+        planes.append((jnp.concatenate([C2, jnp.zeros(lead + (1,))], -1), 0.0))
+    out = _suffix_argmin_take(
+        idx, [(jnp.where(Mx, a, f), f) for a, f in planes])
+    empty = ~Mx.any(-1)
+    out[0] = jnp.where((jnp.arange(P1) == 0) & empty[..., None],
+                       t0[..., None], out[0])
+    return tuple(out)
 
 
 def _aggregate_shares(T, ATTR, M, finish, n_factors: int, R: int):
@@ -955,16 +986,15 @@ def _aggregate_shares(T, ATTR, M, finish, n_factors: int, R: int):
     # piece ends: the next valid piece's start (INF when none — clipped by
     # the effective finish below)
     idx = jnp.where(M, jnp.arange(R), R)
-    nxt = _suffix_min(jnp.concatenate([idx[..., 1:],
-                                       jnp.full(lead + (1,), R)], -1))
-    ends_src = jnp.concatenate([jnp.where(M, T, _INF),
-                                jnp.full(lead + (1,), _INF)], -1)
-    ends = jnp.where(M, jnp.take_along_axis(ends_src, nxt, -1), 0.0)
+    (nxt_T,) = _suffix_argmin_take(
+        _shift_left(idx, 1, R),
+        [(_shift_left(jnp.where(M, T, _INF), 1, _INF), _INF)])
+    ends = jnp.where(M, nxt_T, 0.0)
     # effective finish for never-finishing rows: the START of the trailing
     # equal-attribution run of valid pieces (see the numpy twin)
     seen = M.any(-1)
     last_idx = jnp.where(M, jnp.arange(R), -1).max(-1)
-    last_attr = _gather(ATTR, jnp.maximum(last_idx, 0))
+    last_attr = _pick(ATTR, _onehot(jnp.maximum(last_idx, 0), R))
     bad = M & (ATTR != last_attr[..., None])
     in_run = M & ~_suffix_or(bad)
     run_start = jnp.where(in_run, T, _INF).min(-1)
@@ -1202,12 +1232,12 @@ class JaxSweepEngine:
             for ls, _t0, res in solved:
                 for pi, ps in enumerate(ls.procs):
                     K, L = len(ps.data_names), len(ps.res_names)
-                    cols = np.array(list(range(K))
-                                    + list(range(ls.nC, ls.nC + L)), np.int32)
+                    share = res["share"][pi]
                     out[ps.name] = {
                         "finish": res["finish"][pi],
                         "progress": progress_by[ps.name],
-                        "share": res["share"][pi][:, cols],
+                        "share": jnp.concatenate(
+                            [share[:, :K], share[:, ls.nC:ls.nC + L]], 1),
                         "iterations": res["iterations"],
                     }
             out["__overflow__"] = overflow
@@ -1666,12 +1696,16 @@ def _canon_sig(sig) -> tuple:
 # trace instrumentation: "cut ops not flops" as a tracked number
 # ---------------------------------------------------------------------------
 
-def _jaxpr_counts(jaxpr) -> tuple[int, int, int]:
-    """``(while_loops, body_eqns, total_eqns)`` of a jaxpr, recursively.
+def _jaxpr_counts(jaxpr) -> tuple[int, int, int, int]:
+    """``(while_loops, body_eqns, total_eqns, gathers)`` of a jaxpr,
+    recursively.
 
     ``body_eqns`` sums the equation counts inside every ``while`` body —
     the per-iteration dispatch cost the level-fused engine minimizes;
-    ``total_eqns`` counts every equation at every nesting depth.
+    ``total_eqns`` counts every equation at every nesting depth;
+    ``gathers`` counts the ``gather`` equations at every depth (the TPU
+    runs each as one-element-at-a-time fetches; the engine selects by
+    mask instead, so the count is 0).
     """
     try:
         from jax.extend.core import ClosedJaxpr
@@ -1687,24 +1721,27 @@ def _jaxpr_counts(jaxpr) -> tuple[int, int, int]:
                     if isinstance(u, ClosedJaxpr):
                         yield u.jaxpr
 
-    whiles = body = total = 0
+    whiles = body = total = gathers = 0
     for eqn in jaxpr.eqns:
         total += 1
+        gathers += eqn.primitive.name == "gather"
         if eqn.primitive.name == "while":
             whiles += 1
-            bw, _bb, bt = _jaxpr_counts(eqn.params["body_jaxpr"].jaxpr)
+            bw, _bb, bt, bg = _jaxpr_counts(eqn.params["body_jaxpr"].jaxpr)
             whiles += bw
             body += bt  # bt already counts nested bodies exactly once
             total += bt
-            cw, cb, ct = _jaxpr_counts(eqn.params["cond_jaxpr"].jaxpr)
+            _cw, _cb, ct, cg = _jaxpr_counts(eqn.params["cond_jaxpr"].jaxpr)
             total += ct
+            gathers += bg + cg
         else:
             for sub in subjaxprs(eqn):
-                sw, sb, stot = _jaxpr_counts(sub)
+                sw, sb, stot, sg = _jaxpr_counts(sub)
                 whiles += sw
                 body += sb
                 total += stot
-    return whiles, body, total
+                gathers += sg
+    return whiles, body, total, gathers
 
 
 def trace_report(plan, pack, *, iter_cap: int | None = None) -> dict:
@@ -1712,8 +1749,9 @@ def trace_report(plan, pack, *, iter_cap: int | None = None) -> dict:
 
     Returns ``while_loops`` (one per topology level), ``body_eqns`` (total
     jaxpr equations inside the while bodies — the per-iteration dispatch
-    cost), ``total_eqns`` (all equations at any depth) and ``hlo_lines``
-    (unoptimized StableHLO op lines from ``jit(run).lower``).  Everything is
+    cost), ``total_eqns`` (all equations at any depth), ``gathers``
+    (``gather`` equations at any depth) and ``hlo_lines`` (unoptimized
+    StableHLO op lines from ``jit(run).lower``).  Everything is
     machine-independent, so tests can pin it.
     """
     eng = getattr(plan, "_jax_engine", None) or JaxSweepEngine(plan)
@@ -1722,8 +1760,8 @@ def trace_report(plan, pack, *, iter_cap: int | None = None) -> dict:
     cap = iter_cap or eng._proven_caps.get((B, 1, pack.ramps), eng.iter_cap)
     run = eng._make_run(B, cap, pack.ramps)
     jaxpr = jax.make_jaxpr(run)(largs)
-    whiles, body, total = _jaxpr_counts(jaxpr.jaxpr)
+    whiles, body, total, gathers = _jaxpr_counts(jaxpr.jaxpr)
     hlo = jax.jit(run).lower(largs).as_text()
     hlo_lines = sum(1 for ln in hlo.splitlines() if " = " in ln)
     return {"while_loops": whiles, "body_eqns": body, "total_eqns": total,
-            "hlo_lines": hlo_lines}
+            "gathers": gathers, "hlo_lines": hlo_lines}

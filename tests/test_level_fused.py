@@ -108,6 +108,8 @@ def test_paper_workflow_traces_to_three_while_loops():
     # the per-iteration op budget: 20 % over the 1247 loop-body equations
     # of the first level-fused engine; the count does not depend on B
     assert rep["body_eqns"] <= 1496, rep
+    # every lookup selects by mask: no element gather anywhere in the trace
+    assert rep["gathers"] == 0, rep
 
 
 def test_diamond_traces_to_one_loop_per_level():

@@ -6,6 +6,9 @@ these tests compile the Pallas curve-query kernels and the fused engine at
 the paper workflow's real widths for one chip of a ``v5e:2x2`` topology.
 Nothing runs: a pass says the compiler accepts the program, not that it is
 correct or fast on the chip (``chip_smoke.py`` checks that on a chip).
+The fused sweep is also held to have no ``gather`` in its optimized HLO:
+the TPU runs an element gather one element at a time, and the engine
+selects by mask instead.
 
 The topology is described inside a module fixture, never at import: only
 the worker that runs this file loads the TPU library.  The persistent
@@ -22,13 +25,18 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro import sweep
 from repro.analysis import override, ramp_resource
 from repro.analysis.optimize import _DiffObjective
 from repro.analysis.pack import ThetaMap
 from repro.configs.paper_workflow import (compile_paper_plan, fig7_space,
                                           sweep_scenarios)
+from repro.core import PPoly
 from repro.kernels.ppoly_eval import ops
 from repro.sweep.jax_engine import DEFAULT_ITER_CAP, JaxSweepEngine
+
+from test_fan_in import deploy, fan_in
+from test_level_fused import _diamond
 
 #: the Fig. 7 sweep width and one 128-lane query tile
 B, T = 600, 128
@@ -67,6 +75,11 @@ def topo(no_persistent_cache):
 @pytest.fixture(scope="module")
 def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+def _no_gather(compiled) -> None:
+    n = compiled.as_text().count(" gather(")
+    assert n == 0, f"{n} element gathers in the fused sweep"
 
 
 def _fits_one_chip(compiled) -> None:
@@ -120,7 +133,43 @@ def test_sweep_engine_compiles_for_v5e(ramps, one_chip):
     eng = JaxSweepEngine(plan)
     largs = eng.level_args(pack.host_args(), B, ramps)
     run = jax.jit(eng._make_run(B, DEFAULT_ITER_CAP, ramps))
-    _fits_one_chip(run.lower(_shapes(largs, one_chip)).compile())
+    compiled = run.lower(_shapes(largs, one_chip)).compile()
+    _fits_one_chip(compiled)
+    _no_gather(compiled)
+
+
+def _diamond_pack():
+    """A wide level (5 processes, up to 2 resources, a burst resource), a
+    two-input join gated on a third process."""
+    plan = _diamond(burst=True).compile()
+    return plan, plan.prepare([
+        sweep.Scenario(label=f"m{m}",
+                       resource_inputs={("m1", "mem"): PPoly.constant(m)})
+        for m in (0.5, 1.0, 4.0, 8.0)])
+
+
+def _fan_in_pack():
+    """A 23-input join, as wide as Montage's mConcatFit."""
+    cfg = fan_in(23, "crossover")
+    plan = deploy.build_workflow(cfg).compile()
+    return plan, plan.prepare([
+        deploy.program_scenario({"c.cpu": ("scale", f)},
+                                deploy.data_keys(cfg))
+        for f in (0.5, 1.0, 2.0, 3.0)])
+
+
+@pytest.mark.parametrize("make", [_diamond_pack, _fan_in_pack],
+                         ids=["diamond_burst", "fan_in_23"])
+def test_fan_in_sweep_compiles_gather_free_for_v5e(make, one_chip):
+    """Joins (several ceiling slots) through ``_solve_level`` and the
+    in-trace composition of edge-fed ceilings."""
+    plan, pack = make()
+    eng = JaxSweepEngine(plan)
+    assert max(ls.nC for ls in eng.spec.levels) > 1
+    B = pack.B_batched
+    largs = eng.level_args(pack.host_args(), B, pack.ramps)
+    run = jax.jit(eng._make_run(B, DEFAULT_ITER_CAP, pack.ramps))
+    _no_gather(run.lower(_shapes(largs, one_chip)).compile())
 
 
 def test_diff_engine_compiles_for_v5e(one_chip):
